@@ -433,10 +433,10 @@ def _serve_with_outputs(eng, round_idx: int):
 
 
 def sharded_sweep(quick: bool) -> dict:
-    """The mesh sweep body — must run in a process with >= 8 devices
-    (``bench_sharded`` re-execs this file under forced host devices when
-    needed).  Every engine serves the SAME rounds of the acceptance
-    workload, so greedy outputs are comparable bit-for-bit."""
+    """The mesh sweep body, over the shapes this process's devices allow
+    (on a CPU-only host ``bench_sharded`` re-execs this file under 8
+    forced host devices).  Every engine serves the SAME rounds of the
+    acceptance workload, so greedy outputs are comparable bit-for-bit."""
     import time
 
     from repro.launch.mesh import mesh_for_serving
@@ -511,13 +511,17 @@ def bench_sharded(quick: bool) -> None:
     import time
 
     t0 = time.perf_counter()
-    if len(jax.devices()) >= 8:
+    if jax.default_backend() != "cpu" or len(jax.devices()) >= 8:
+        # accelerators (or enough host devices): sweep in this process
+        # over the shapes its devices allow — a child could not take
+        # chips this process already holds
         res = sharded_sweep(quick)
     else:
-        # forced host devices must be set before jax import -> subprocess
+        # forced host devices must be set before jax import -> a CPU
+        # child process (it never asks for an accelerator)
         env = dict(os.environ,
                    XLA_FLAGS="--xla_force_host_platform_device_count=8",
-                   REPRO_ALLOW_MULTIDEVICE="1")
+                   REPRO_ALLOW_MULTIDEVICE="1", JAX_PLATFORMS="cpu")
         cmd = [sys.executable, os.path.abspath(__file__),
                "--sharded-worker"] + (["--quick"] if quick else [])
         out = subprocess.run(cmd, capture_output=True, text=True,

@@ -432,13 +432,16 @@ def flush_tensor(t: Tensor) -> None:
     # boundaries inside a chain are prevented at enqueue time)
     needs_grad = any(x._pending.needs_grad for x in by_slot)
 
-    wrap = None
+    # a chain lowered to the Pallas kernel counts under its own op name
+    # in dispatch_cache_stats()["per_op"], so a chip run can show that
+    # fusion ran natively
+    wrap, op_name = None, "__fused__"
     if (_can_use_pallas(ext_data, pend.shape)
             and all(x._pending.shape == pend.shape for x in by_slot)):
         from ..kernels.ops import make_fused_elementwise
-        wrap = make_fused_elementwise
+        wrap, op_name = make_fused_elementwise, "__fused_pallas__"
 
-    key = _dispatch.make_key("__fused__", descriptor, ext_data,
+    key = _dispatch.make_key(op_name, descriptor, ext_data,
                              bool(needs_grad))
     if key is not None and _dispatch.is_enabled():
         entry = _dispatch.dispatch_cache().get_or_create(

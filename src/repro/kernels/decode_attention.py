@@ -237,10 +237,12 @@ def mixed_attention_fwd(q: jnp.ndarray, k_cache: jnp.ndarray,
 
 def _paged_kernel(tbl_ref, seg_ref, pos_ref, q_ref, *refs,
                   scale: float, window: Optional[int], page_size: int,
-                  ppt: int, quantized: bool):
+                  ppt: int, n_kv_heads: int, quantized: bool):
     # refs layout (set up by paged_attention_fwd): ppt K page refs,
     # ppt V page refs, [ppt K-scale refs, ppt V-scale refs when
-    # quantized], then o_ref and the three VMEM scratch refs.
+    # quantized], then o_ref and the three VMEM scratch refs.  Each
+    # page ref holds ALL KV heads of one page, (1, ps, Hkv, D); the
+    # head loop below is static.
     k_refs = refs[:ppt]
     v_refs = refs[ppt:2 * ppt]
     if quantized:
@@ -251,8 +253,8 @@ def _paged_kernel(tbl_ref, seg_ref, pos_ref, q_ref, *refs,
         o_ref, m_scr, l_scr, acc_scr = refs[2 * ppt:]
 
     t = pl.program_id(0)
-    ti_ = pl.program_id(2)
-    nt = pl.num_programs(2)
+    ti_ = pl.program_id(1)
+    nt = pl.num_programs(1)
 
     @pl.when(ti_ == 0)
     def _init():
@@ -262,14 +264,14 @@ def _paged_kernel(tbl_ref, seg_ref, pos_ref, q_ref, *refs,
 
     pos = pos_ref[t]
     # the tile packs ppt consecutive pages of token t's sequence; each
-    # page j runs the SAME sequential online-softmax update the
-    # single-page grid would, in the same order — fp32 outputs are
-    # bitwise-equal for any tile size.  Only pages at or before the
-    # token's own position hold live keys (causal); a tile page past
-    # the table width is index-clamped in the BlockSpec map and its
-    # k_start > pos predicate skips the compute.  Padding tokens
-    # (seg<0) route to page-table row 0 and the caller discards their
-    # output.
+    # page j runs, per KV head, the SAME sequential online-softmax
+    # update the single-page grid would, in the same order — fp32
+    # outputs are bitwise-equal for any tile size.  Only pages at or
+    # before the token's own position hold live keys (causal); a tile
+    # page past the table width is index-clamped in the BlockSpec map
+    # and its k_start > pos predicate skips the compute.  Padding
+    # tokens (seg<0) route to page-table row 0 and the caller discards
+    # their output.
     for j in range(ppt):
         k_start = (ti_ * ppt + j) * page_size
         run = k_start <= pos
@@ -279,44 +281,45 @@ def _paged_kernel(tbl_ref, seg_ref, pos_ref, q_ref, *refs,
 
         @pl.when(run)
         def _body(j=j, k_start=k_start):
-            q = q_ref[0, 0]                           # (G, D)
-            k = k_refs[j][0, :, 0]                    # (ps, D)
-            v = v_refs[j][0, :, 0]
-            if quantized:
-                # dequantize IN KERNEL: codes × per-(token, head)
-                # scales — the fp32 pool never materializes in HBM
-                q = q.astype(jnp.float32)
-                k = k.astype(jnp.float32) \
-                    * ks_refs[j][0, :, 0][:, None]
-                v = v.astype(jnp.float32) \
-                    * vs_refs[j][0, :, 0][:, None]
-            scores = pl.dot(q, k, trans_b=True).astype(jnp.float32) \
-                * scale
+            for h in range(n_kv_heads):
+                q = q_ref[0, h]                       # (G, D)
+                k = k_refs[j][0, :, h, :]             # (ps, D)
+                v = v_refs[j][0, :, h, :]
+                if quantized:
+                    # dequantize IN KERNEL: codes × per-(token, head)
+                    # scales — the fp32 pool never materializes in HBM
+                    q = q.astype(jnp.float32)
+                    k = k.astype(jnp.float32) \
+                        * ks_refs[j][0, :, h][:, None]
+                    v = v.astype(jnp.float32) \
+                        * vs_refs[j][0, :, h][:, None]
+                scores = pl.dot(q, k, trans_b=True).astype(jnp.float32) \
+                    * scale
 
-            k_pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, scores.shape, 1)
-            mask = k_pos <= pos
-            if window is not None:
-                mask = jnp.logical_and(mask, k_pos > pos - window)
-            scores = jnp.where(mask, scores, NEG_INF)
+                k_pos = k_start + jax.lax.broadcasted_iota(
+                    jnp.int32, scores.shape, 1)
+                mask = k_pos <= pos
+                if window is not None:
+                    mask = jnp.logical_and(mask, k_pos > pos - window)
+                scores = jnp.where(mask, scores, NEG_INF)
 
-            m_prev = m_scr[:, :1]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(scores, axis=-1, keepdims=True))
-            p = jnp.exp(scores - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_scr[...] = jnp.broadcast_to(
-                alpha * l_scr[:, :1]
-                + jnp.sum(p, axis=-1, keepdims=True),
-                l_scr.shape)
-            acc_scr[...] = acc_scr[...] * alpha + pl.dot(
-                p.astype(v.dtype), v).astype(jnp.float32)
-            m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+                m_prev = m_scr[h, :, :1]
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(scores, axis=-1, keepdims=True))
+                p = jnp.exp(scores - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_scr[h] = jnp.broadcast_to(
+                    alpha * l_scr[h, :, :1]
+                    + jnp.sum(p, axis=-1, keepdims=True),
+                    l_scr.shape[1:])
+                acc_scr[h] = acc_scr[h] * alpha + pl.dot(
+                    p.astype(v.dtype), v).astype(jnp.float32)
+                m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
 
     @pl.when(ti_ == nt - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :, :1], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def paged_attention_fwd(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -333,13 +336,19 @@ def paged_attention_fwd(q: jnp.ndarray, k_pages: jnp.ndarray,
     seg_ids/positions: (T,) int32.  All three index operands are
     scalar-prefetched: the KV BlockSpec index map reads
     ``tables[seg_ids[t], pi]`` before the body runs, so each grid step
-    DMAs exactly one physical page into VMEM — the gather disappears
-    into the memory system.
+    DMAs exactly the physical pages it attends into VMEM — the gather
+    disappears into the memory system.
+
+    The grid is (T, page tiles).  A page block carries every KV head of
+    its page, (1, ps, Hkv, D): its last two dims equal the array's, the
+    form the TPU compiler accepts for any Hkv (a one-head block,
+    (1, ps, 1, D), is refused whenever Hkv > 1).  The kernel loops over
+    the heads statically, so each page is fetched once per token.
 
     Quantized pools pass ``k_scale``/``v_scale``: (N, ps, Hkv) fp32
     per-(token, head) scales.  They ride the SAME table-prefetch
-    routing as the pages — their BlockSpecs share the kv index map, so
-    the scale row for a page arrives with the page and dequantization
+    routing as the pages — their BlockSpecs share the page index, so
+    the scale rows for a page arrive with the page and dequantization
     happens in VMEM, never materializing an fp32 pool.
 
     ``pages_per_tile`` statically packs several pages into one grid
@@ -356,51 +365,49 @@ def paged_attention_fwd(q: jnp.ndarray, k_pages: jnp.ndarray,
     quantized = k_scale is not None
 
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,
-                               page_size=ps, ppt=ppt,
+                               page_size=ps, ppt=ppt, n_kv_heads=hkv,
                                quantized=quantized)
 
+    def page_index(j, ti, tj, tbl, seg):
+        slot = jnp.clip(seg[ti], 0, s_slots - 1)
+        # pages past the table width clamp to the last column; the
+        # kernel's k_start <= pos predicate masks their compute
+        return tbl[slot, jnp.minimum(tj * ppt + j, p_pages - 1)]
+
     def page_map(j):
-        def kv_map(ti, h, tj, tbl, seg, pos):
-            slot = jnp.clip(seg[ti], 0, s_slots - 1)
-            # pages past the table width clamp to the last column; the
-            # kernel's k_start <= pos predicate masks their compute
-            page = jnp.minimum(tj * ppt + j, p_pages - 1)
-            return (tbl[slot, page], 0, h, 0)
+        def kv_map(ti, tj, tbl, seg, pos):
+            return (page_index(j, ti, tj, tbl, seg), 0, 0, 0)
         return kv_map
 
     def scale_map(j):
-        def sc_map(ti, h, tj, tbl, seg, pos):
-            slot = jnp.clip(seg[ti], 0, s_slots - 1)
-            page = jnp.minimum(tj * ppt + j, p_pages - 1)
-            return (tbl[slot, page], 0, h)
+        def sc_map(ti, tj, tbl, seg, pos):
+            return (page_index(j, ti, tj, tbl, seg), 0, 0)
         return sc_map
 
-    in_specs = [pl.BlockSpec((1, 1, g, d),
-                             lambda ti, h, tj, tbl, seg, pos:
-                             (ti, h, 0, 0))]
-    in_specs += [pl.BlockSpec((1, ps, 1, d), page_map(j))
+    tok_spec = pl.BlockSpec((1, hkv, g, d),
+                            lambda ti, tj, tbl, seg, pos: (ti, 0, 0, 0))
+    in_specs = [tok_spec]
+    in_specs += [pl.BlockSpec((1, ps, hkv, d), page_map(j))
                  for j in range(ppt)]
-    in_specs += [pl.BlockSpec((1, ps, 1, d), page_map(j))
+    in_specs += [pl.BlockSpec((1, ps, hkv, d), page_map(j))
                  for j in range(ppt)]
     operands = [q] + [k_pages] * ppt + [v_pages] * ppt
     if quantized:
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_map(j))
+        in_specs += [pl.BlockSpec((1, ps, hkv), scale_map(j))
                      for j in range(ppt)]
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_map(j))
+        in_specs += [pl.BlockSpec((1, ps, hkv), scale_map(j))
                      for j in range(ppt)]
         operands += [k_scale] * ppt + [v_scale] * ppt
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(t, hkv, n_tiles),
+        grid=(t, n_tiles),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda ti, h, tj, tbl, seg, pos:
-                               (ti, h, 0, 0)),
+        out_specs=tok_spec,
         scratch_shapes=[
-            pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((hkv, g, 128), jnp.float32),
+            pltpu.VMEM((hkv, g, 128), jnp.float32),
+            pltpu.VMEM((hkv, g, d), jnp.float32),
         ],
     )
 

@@ -2,8 +2,9 @@
 
 Each op:
   * normalizes layouts (GQA head grouping, lane-width padding),
-  * runs the Pallas kernel (interpret mode automatically on CPU so the
-    same code validates here and runs native on TPU),
+  * runs the Pallas kernel: natively on TPU, in interpret mode on the
+    CPU backend only (tests), so any other backend reaches the Pallas
+    compiler and fails loudly instead of interpreting in silence,
   * exposes a ``jax.custom_vjp``: forward = kernel, backward = JAX AD
     through the ``ref.py`` oracle with recomputation (flash-style
     recompute; a fused backward kernel is a further optimization noted in
@@ -31,7 +32,7 @@ LANE = 128
 
 @functools.cache
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def _pad_last(x: jnp.ndarray, to: int) -> jnp.ndarray:
@@ -331,24 +332,6 @@ def fused_elementwise(fn, *xs, interpret: Optional[bool] = None):
     )(*[prep(x) for x in xs])
     result = tuple(o.reshape(-1)[:n].reshape(shape) for o in out2d)
     return result[0] if single else result
-
-
-def gumbel_perturb(logits: jnp.ndarray,
-                   uniform: jnp.ndarray) -> jnp.ndarray:
-    """Gumbel-max perturbation for in-jit sampling: ``logits +
-    (-log(-log(u)))`` as ONE fused elementwise kernel.
-
-    ``argmax`` of the result is a categorical draw from
-    ``softmax(logits)`` (the Gumbel-max trick) — the serving sampler
-    applies it to top-k/top-p-filtered logits so masked lanes
-    (``-inf``) can never win.  ``uniform`` must be in (0, 1); shapes
-    must match.  Elementwise, so the fusion-queue Pallas lowering
-    (`fused_elementwise`) runs it as a single VPU pass on TPU and a
-    single XLA fusion elsewhere."""
-    def perturb(lg, u):
-        return lg + -jnp.log(-jnp.log(u))
-    return fused_elementwise(perturb, logits.astype(jnp.float32),
-                             uniform.astype(jnp.float32))
 
 
 def make_fused_elementwise(fn):

@@ -22,18 +22,26 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]
               ) -> jax.sharding.Mesh:
-    """Arbitrary mesh for tests/examples (e.g. (1,1) on one CPU)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh for tests/examples (e.g. (1,1) on one CPU).
+
+    Every axis is ``AxisType.Auto``: the sharding rules here annotate
+    params and activations with ``NamedSharding``/constraints and let
+    GSPMD propagate the rest.  ``jax.make_mesh``'s default (Explicit
+    axes) would instead make sharding part of every array's type, and
+    ops such as the embedding gather raise ``ShardingTypeError``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(tp: Optional[int] = None) -> jax.sharding.Mesh:
@@ -50,7 +58,7 @@ def make_local_mesh(tp: Optional[int] = None) -> jax.sharding.Mesh:
         raise MeshConfigError(
             f"tp={tp} must be >= 1 and divide the local device "
             f"count ({n})")
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return make_mesh((n // tp, tp), ("data", "model"))
 
 
 def mesh_for_serving(n_devices: Optional[int] = None, tp: int = 1
@@ -74,7 +82,8 @@ def mesh_for_serving(n_devices: Optional[int] = None, tp: int = 1
         raise MeshConfigError(
             f"tp={tp} must be >= 1 and divide n_devices={n}")
     devices = np.asarray(jax.devices()[:n]).reshape(n // tp, tp)
-    return jax.sharding.Mesh(devices, ("data", "model"))
+    return jax.sharding.Mesh(devices, ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
 
 
 def data_axis_names(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

@@ -1,6 +1,7 @@
 """Serving entry point for the scheduler/executor engine.
 
-    PYTHONPATH=src python -m repro.launch.serve [--preset tiny|small]
+    PYTHONPATH=src python -m repro.launch.serve [--preset tiny|small |
+        --config ARCH] [--seed S]
         [--requests 32] [--max-new 8] [--chunk 16] [--json PATH]
         [--timeout-ms T] [--ttft-deadline-ms T] [--max-queue-depth N]
         [--faults SPEC] [--fault-seed S]
@@ -20,16 +21,27 @@ engine and prints partial outputs instead of dying mid-decode.  Fault
 injection (``--faults "nan_logits@6;pool_exhaustion@4:pages=16"``, or
 env ``REPRO_FAULTS``) exercises those paths deterministically.
 
-The big configs under ``repro.configs`` serve through the same engine on
-real accelerators; the presets here keep the entry point runnable on a
-laptop CPU (the paper's §2 "everyone's workflow must work locally").
+``--config ARCH`` serves a published architecture from
+``repro.configs`` at its full width in bf16, with random weights made on
+the device from ``--seed`` (nothing is downloaded); the presets keep the
+entry point runnable on a laptop CPU (the paper's §2 "everyone's
+workflow must work locally").  :func:`build_engine` is the one
+constructor behind this launcher and ``launch/server.py``.
+
+The process exits non-zero when a request failed and no faults were
+injected (``--faults`` / ``REPRO_FAULTS``): a failure nobody asked for
+is the program's fault.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
+import sys
 import time
+from dataclasses import replace
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +50,7 @@ from ..models.lm import LMConfig, init_params
 from ..serving.engine import ServingEngine
 from ..serving.errors import ServingError
 from ..serving.faults import FaultInjector
+from .compile_cache import enable_compile_cache
 
 PRESETS = {
     "tiny": dict(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
@@ -45,6 +58,49 @@ PRESETS = {
     "small": dict(n_layers=4, d_model=256, n_heads=8, n_kv_heads=4,
                   d_ff=512, vocab_size=1024),
 }
+
+
+def model_config(preset: Optional[str] = None,
+                 config: Optional[str] = None) -> LMConfig:
+    """``config``: a published ``repro.configs`` architecture in bf16,
+    asking for the Pallas attention kernel explicitly (the executor's
+    ``select_paged_backend`` takes the reference path under a mesh).
+    Else the fp32 ``preset``."""
+    if config is not None:
+        from ..configs import get_config
+        return replace(get_config(config), param_dtype=jnp.bfloat16,
+                       attn_backend="pallas")
+    return LMConfig(name=f"serve-{preset}", **PRESETS[preset],
+                    param_dtype=jnp.float32, remat="none")
+
+
+def device_params(cfg: LMConfig, seed: int, mesh=None):
+    """Random weights made ON the device from ``seed`` by a jitted
+    ``init_params`` (never built on the host and copied); under a mesh
+    each array is created directly in its serving sharding."""
+    init, key = functools.partial(init_params, cfg), jax.random.key(seed)
+    shardings = None
+    if mesh is not None:
+        from ..distributed.sharding import serving_param_shardings
+        shardings = serving_param_shardings(
+            cfg, jax.eval_shape(init, key), mesh)
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
+def build_engine(preset: Optional[str] = "tiny",
+                 config: Optional[str] = None, *, seed: int = 0,
+                 dp: int = 1, tp: int = 1, **engine_kw) -> ServingEngine:
+    """The serving engine both launchers front: ``config`` (a
+    ``repro.configs`` name) or ``preset``, over a ``(dp, tp)`` mesh
+    when ``dp * tp > 1``.  ``engine_kw`` goes to :class:`ServingEngine`
+    (page_size, num_pages, max_batch, chunk_size, ...)."""
+    mesh = None
+    if dp * tp > 1:
+        from .mesh import mesh_for_serving
+        mesh = mesh_for_serving(dp * tp, tp=tp)
+    cfg = model_config(preset, config)
+    return ServingEngine(cfg, device_params(cfg, seed, mesh), mesh=mesh,
+                         **engine_kw)
 
 
 def synthetic_workload(n_requests: int, vocab: int):
@@ -59,6 +115,11 @@ def synthetic_workload(n_requests: int, vocab: int):
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=sorted(PRESETS), default="tiny")
+    ap.add_argument("--config", default=None, metavar="ARCH",
+                    help="serve a published repro.configs architecture "
+                         "at full width in bf16 (overrides --preset)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights")
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--chunk", type=int, default=16)
@@ -91,23 +152,16 @@ def main() -> None:
                     help="also dump metrics JSON to this path")
     args = ap.parse_args()
 
-    cfg = LMConfig(name=f"serve-{args.preset}", **PRESETS[args.preset],
-                   param_dtype=jnp.float32, remat="none",
-                   attn_backend="ref")
-    params = init_params(cfg, jax.random.key(0))
+    enable_compile_cache()
     faults = FaultInjector.parse(args.faults, seed=args.fault_seed) \
         if args.faults else None
-    mesh = None
-    if args.dp * args.tp > 1:
-        from .mesh import mesh_for_serving
-        mesh = mesh_for_serving(args.dp * args.tp, tp=args.tp)
-    eng = ServingEngine(cfg, params, page_size=args.page_size,
-                        num_pages=args.num_pages,
-                        max_batch=args.max_batch,
-                        chunk_size=args.chunk,
-                        max_queue_depth=args.max_queue_depth,
-                        kv_dtype=args.kv_dtype,
-                        faults=faults, mesh=mesh)
+    eng = build_engine(args.preset, args.config, seed=args.seed,
+                       dp=args.dp, tp=args.tp, page_size=args.page_size,
+                       num_pages=args.num_pages, max_batch=args.max_batch,
+                       chunk_size=args.chunk,
+                       max_queue_depth=args.max_queue_depth,
+                       kv_dtype=args.kv_dtype, faults=faults)
+    cfg = eng.cfg
 
     prompts = synthetic_workload(args.requests, cfg.vocab_size)
     t0 = time.perf_counter()
@@ -173,6 +227,12 @@ def main() -> None:
         with open(args.json, "w") as f:
             json.dump(report, f, indent=2)
         print(f"[json] {args.json}")
+    if eng.faults is None and (m["failed_requests"]
+                               or m["executor_failures"]):
+        print(f"[error] {m['failed_requests']} failed request(s), "
+              f"{m['executor_failures']} executor failure(s) with no "
+              f"faults injected", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -1,6 +1,7 @@
 """Streaming HTTP/SSE serving entry point.
 
-    PYTHONPATH=src python -m repro.launch.server [--preset tiny|small]
+    PYTHONPATH=src python -m repro.launch.server [--preset tiny|small |
+        --config ARCH] [--seed S]
         [--host 127.0.0.1] [--port 8008] [--num-pages N]
         [--hwm-frac F] [--max-stream-tokens N] [--selftest N]
 
@@ -40,14 +41,10 @@ import asyncio
 import json
 from typing import AsyncIterator, Dict, List, Optional, Tuple
 
-import jax
-import jax.numpy as jnp
-
-from ..models.lm import LMConfig, init_params
-from ..serving.engine import ServingEngine
 from ..serving.errors import AdmissionRejected, BackpressureRejected
 from ..serving.frontend import AsyncFrontend
-from .serve import PRESETS
+from .compile_cache import enable_compile_cache
+from .serve import PRESETS, build_engine
 
 __all__ = ["HttpFrontendServer", "sse_client", "main"]
 
@@ -66,23 +63,24 @@ def _sse(event: str, data: dict) -> bytes:
 class HttpFrontendServer:
     """Raw-asyncio HTTP/SSE wrapper around an :class:`AsyncFrontend`.
 
-    ``start`` binds the socket and spawns the engine-pump task;
-    ``stop`` drains both.  The server object exposes ``port`` after
-    ``start`` so tests can bind port 0."""
+    ``start`` binds the socket and spawns the engine-pump task
+    (``pump_task``: it ends with the engine's exception if a step
+    raises); ``stop`` drains both.  The server object exposes ``port``
+    after ``start`` so tests can bind port 0."""
 
     def __init__(self, frontend: AsyncFrontend, host: str = "127.0.0.1",
                  port: int = 8008):
         self.frontend = frontend
         self.host, self.port = host, port
         self._server: Optional[asyncio.AbstractServer] = None
-        self._pump_task: Optional[asyncio.Task] = None
+        self.pump_task: Optional[asyncio.Task] = None
 
     async def start(self) -> None:
         """Bind the listening socket and start the engine-pump task."""
         self._server = await asyncio.start_server(
             self._handle, self.host, self.port)
         self.port = self._server.sockets[0].getsockname()[1]
-        self._pump_task = asyncio.ensure_future(self.frontend.run())
+        self.pump_task = asyncio.ensure_future(self.frontend.run())
 
     async def stop(self) -> None:
         """Close the socket, stop the pump, cancel open streams."""
@@ -90,8 +88,8 @@ class HttpFrontendServer:
             self._server.close()
             await self._server.wait_closed()
         self.frontend.close()
-        if self._pump_task is not None:
-            await self._pump_task
+        if self.pump_task is not None:
+            await self.pump_task
 
     # -- request handling ---------------------------------------------------
     async def _read_request(self, reader: asyncio.StreamReader
@@ -252,19 +250,6 @@ async def sse_client(host: str, port: int, spec: dict,
             pass
 
 
-def build_engine(preset: str, *, num_pages: int, page_size: int,
-                 max_batch: int, chunk: int) -> ServingEngine:
-    """Construct the preset engine the server fronts (same presets as
-    ``launch.serve`` so the two entry points stay comparable)."""
-    cfg = LMConfig(name=f"server-{preset}", **PRESETS[preset],
-                   param_dtype=jnp.float32, remat="none",
-                   attn_backend="ref")
-    params = init_params(cfg, jax.random.key(0))
-    return ServingEngine(cfg, params, page_size=page_size,
-                         num_pages=num_pages, max_batch=max_batch,
-                         chunk_size=chunk)
-
-
 async def _selftest(server: HttpFrontendServer, n: int,
                     vocab: int) -> int:
     """Drive ``n`` streams through a real socket; return the number
@@ -289,6 +274,11 @@ async def _selftest(server: HttpFrontendServer, n: int,
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=sorted(PRESETS), default="tiny")
+    ap.add_argument("--config", default=None, metavar="ARCH",
+                    help="serve a published repro.configs architecture "
+                         "at full width in bf16 (overrides --preset)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8008)
     ap.add_argument("--num-pages", type=int, default=256)
@@ -305,9 +295,10 @@ def main() -> None:
                          "ephemeral port, print metrics, and exit")
     args = ap.parse_args()
 
-    eng = build_engine(args.preset, num_pages=args.num_pages,
-                       page_size=args.page_size,
-                       max_batch=args.max_batch, chunk=args.chunk)
+    enable_compile_cache()
+    eng = build_engine(args.preset, args.config, seed=args.seed,
+                       num_pages=args.num_pages, page_size=args.page_size,
+                       max_batch=args.max_batch, chunk_size=args.chunk)
     fe = AsyncFrontend(eng, hwm_frac=args.hwm_frac,
                        max_queue_depth=args.max_queue_depth,
                        max_stream_tokens=args.max_stream_tokens)
@@ -317,10 +308,10 @@ def main() -> None:
     async def serve() -> int:
         await server.start()
         print(f"[server] listening on http://{server.host}:{server.port}"
-              f"  (preset={args.preset})")
+              f"  (model={eng.cfg.name})")
         if args.selftest is not None:
-            vocab = PRESETS[args.preset]["vocab_size"]
-            ok = await _selftest(server, args.selftest, vocab)
+            ok = await _selftest(server, args.selftest,
+                                 eng.cfg.vocab_size)
             await server.stop()
             print(json.dumps(server.frontend.stats(), default=str,
                              indent=2))

@@ -5,8 +5,11 @@ path and the functional LM models.  It handles:
 
   * GQA/MQA: k/v with fewer heads than q are broadcast per group,
   * causal masking, sliding-window (local) masking, explicit masks,
-  * backend selection: "ref" (pure jnp, the oracle), "pallas" (flash
-    kernel), "auto" (pallas when available for the shape, else ref).
+  * backend selection: "ref" (pure jnp, the oracle), "pallas" (the
+    Pallas kernel), "auto" (the kernel for every input it is built for,
+    by shape — see each entry point; on TPU the paged path always takes
+    it).  A kernel error propagates: no path catches it and falls back
+    to the reference, so a chip run never times the wrong code.
 
 All reference math upcasts softmax statistics to f32, matching the Pallas
 kernels bit-for-bit in structure so allclose checks are tight.
@@ -139,14 +142,12 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             return context_sdpa(q, k, v, scale, is_causal, window)
         return sdpa_ref(q, k, v, mask, is_causal, scale, window)
     if backend in ("auto", "pallas"):
+        # the flash kernel takes no explicit mask, and below
+        # _PALLAS_MIN_SEQ the reference is cheaper than tiling
         if mask is None and q.shape[2] >= _PALLAS_MIN_SEQ:
-            try:
-                from ..kernels import ops as kops
-                return kops.flash_attention(
-                    q, k, v, causal=is_causal, scale=scale, window=window)
-            except Exception:
-                if backend == "pallas":
-                    raise
+            from ..kernels import ops as kops
+            return kops.flash_attention(
+                q, k, v, causal=is_causal, scale=scale, window=window)
         return sdpa_ref(q, k, v, mask, is_causal, scale, window)
     raise ValueError(f"unknown sdpa backend {backend!r}")
 
@@ -173,14 +174,9 @@ def mixed_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     scale = scale if scale is not None else d ** -0.5
 
     if backend in ("auto", "pallas"):
-        try:
-            from ..kernels import ops as kops
-            return kops.mixed_attention(q, k_cache, v_cache, seg_ids,
-                                        positions, scale=scale,
-                                        window=window)
-        except Exception:
-            if backend == "pallas":
-                raise
+        from ..kernels import ops as kops
+        return kops.mixed_attention(q, k_cache, v_cache, seg_ids,
+                                    positions, scale=scale, window=window)
 
     seg = jnp.clip(seg_ids, 0, s - 1)
     k = jnp.take(k_cache, seg, axis=0)                  # (T, Hkv, L, D)
@@ -231,29 +227,28 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
 
     Backends: "pallas" runs the block-table-prefetching kernel (the
     production TPU path: the table lookup happens in the BlockSpec index
-    map, so only live pages are ever DMA'd); "ref"/fallback gathers
-    (S, P*ps) page rows with one ``jnp.take`` and reduces to
-    ``mixed_attention`` — the oracle, and the XLA-fused CPU path.
+    map, so only live pages are ever DMA'd); "ref" (and "auto" on CPU
+    with an unaligned head_dim) gathers (S, P*ps) page rows with one
+    ``jnp.take`` and reduces to ``mixed_attention`` — the oracle, and
+    the XLA-fused CPU path.
     """
     t, hq, d = q.shape
     n_pages, ps, hkv, _ = k_pages.shape
     s, p = tables.shape
     scale = scale if scale is not None else d ** -0.5
 
-    # auto: take the kernel only when head_dim is lane-aligned — for
-    # d % 128 != 0 the wrapper would lane-pad (copy) the ENTIRE page
-    # pool per layer per step, costing more than the gather it saves
-    if backend == "pallas" or (backend == "auto" and d % 128 == 0):
-        try:
-            from ..kernels import ops as kops
-            return kops.paged_attention(q, k_pages, v_pages, tables,
-                                        seg_ids, positions, scale=scale,
-                                        window=window, k_scale=k_scale,
-                                        v_scale=v_scale,
-                                        pages_per_tile=pages_per_tile)
-        except Exception:
-            if backend == "pallas":
-                raise
+    # auto: on TPU always the kernel.  Elsewhere (the CPU interpret
+    # path) only when head_dim is lane-aligned — for d % 128 != 0 the
+    # wrapper lane-pads (copies) the ENTIRE page pool per layer per
+    # step, costing more than the gather it saves
+    if backend == "pallas" or (backend == "auto" and (
+            d % 128 == 0 or jax.default_backend() == "tpu")):
+        from ..kernels import ops as kops
+        return kops.paged_attention(q, k_pages, v_pages, tables,
+                                    seg_ids, positions, scale=scale,
+                                    window=window, k_scale=k_scale,
+                                    v_scale=v_scale,
+                                    pages_per_tile=pages_per_tile)
 
     if k_scale is not None:
         # ref dequant: codes × scales materialize an fp32 pool view
@@ -311,13 +306,9 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     scale = scale if scale is not None else d ** -0.5
 
     if backend in ("auto", "pallas"):
-        try:
-            from ..kernels import ops as kops
-            return kops.decode_attention(q, k_cache, v_cache, cache_len,
-                                         scale=scale, window=window)
-        except Exception:
-            if backend == "pallas":
-                raise
+        from ..kernels import ops as kops
+        return kops.decode_attention(q, k_cache, v_cache, cache_len,
+                                     scale=scale, window=window)
 
     k = repeat_kv(k_cache, hq // hkv)
     v = repeat_kv(v_cache, hq // hkv)

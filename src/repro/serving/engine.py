@@ -29,7 +29,8 @@ Loop per step:
      ``metrics["spec_acceptance_rate"]``.
 
 Fault tolerance wraps the loop (the robustness half of "serve heavy
-traffic from millions of users"): a flagged or crashed or corrupted
+traffic from millions of users"): a flagged sequence, a typed
+executor fault (:class:`~.errors.RequestFailed`) or a corrupted
 sequence is QUARANTINED — state FAILED, pages reclaimed+scrubbed via
 ``kv.recover()``, device tables force-rebuilt — and the engine keeps
 serving everyone else.  The invariant watchdog (``watchdog.Watchdog``)
@@ -94,7 +95,6 @@ class ServingEngine:
                     "paged engine serves full-attention models; use the "
                     "dense-cache pjit path for hybrid/ssm archs")
         self.cfg = cfg
-        self.params = params
         self.max_batch = max_batch
         # sharded serving: a (data, model) mesh replicates the slot
         # space over `data` (S slots -> n_replicas*S slots; `num_pages`
@@ -253,8 +253,9 @@ class ServingEngine:
 
     def _step(self) -> Optional[List[Request]]:
         """One unified continuous-batching step (admission + plan +
-        execute + commit), with the executor boundary treated as a
-        fault line.  None = nothing runnable."""
+        execute + commit), with typed request faults at the executor
+        boundary treated as a fault line; any other executor exception
+        propagates.  None = nothing runnable."""
         self._step_no += 1
         if self.faults is not None:
             self.faults.before_plan(self._step_no, self.scheduler,
@@ -268,17 +269,15 @@ class ServingEngine:
                                            self.scheduler, self.kv)
             next_tokens, bad = self.executor.execute(plan, self.kv)
         except RequestFailed as e:
-            # attributed executor fault: fail the culprit, keep serving
+            # a typed request fault: fail the culprit, keep serving.  Any
+            # other exception (a compile or lowering error, a device
+            # fault) is the program's, not a request's: it propagates
             self._counters["executor_failures"] += 1
             if e.req_id is not None and \
                     self.scheduler._lookup(e.req_id) is not None:
                 self._quarantine(e.req_id, f"executor fault: {e}")
             else:
                 self._unattributed_failure(plan, e)
-            return []
-        except Exception as e:          # noqa: BLE001 — fault line
-            self._counters["executor_failures"] += 1
-            self._unattributed_failure(plan, e)
             return []
         self._exec_fail_streak = 0
         if bad.any():

@@ -17,6 +17,10 @@ runs the whole step's compute as a single XLA executable:
   * the KV page arrays are DONATED: ``unified_step`` consumes them and
     returns the updated pair; while the step runs the host holds no
     alias (``PagedKVCache.take_kv``/``put_kv`` enforce this),
+  * the weights are OPERANDS of the step (not donated), never closure
+    constants: the lowered module carries no weight bytes (a multi-GB
+    model would pass the 2 GB protobuf limit), and the compile-cache
+    key depends on shapes only,
   * SAMPLING runs in the same executable (``serving.sampling``):
     greedy / temperature / top-k / top-p with per-slot params as tiny
     operand arrays and position-keyed PRNG — plus the K speculative
@@ -51,6 +55,14 @@ from .scheduler import StepPlan
 # buffer donation is a TPU/GPU optimization; CPU (tests) just warns
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
+
+
+def serving_params(cfg: LM.LMConfig, params) -> dict:
+    """The step's weight operand: the model's top-level arrays (embed,
+    final norm, lm_head) plus ``"layers"``, the per-layer list."""
+    out = {k: v for k, v in params.items() if k not in ("groups", "tail")}
+    out["layers"] = split_layer_params(cfg, params)
+    return out
 
 
 def split_layer_params(cfg: LM.LMConfig, params) -> list:
@@ -88,8 +100,7 @@ class Executor:
                 jax.device_put, params,
                 serving_param_shardings(cfg, params, mesh))
         self.n_replicas = n_replicas
-        self.params = params
-        self._layer_params = split_layer_params(cfg, params)
+        self._params = serving_params(cfg, params)
         # a replica axis (vmap) or a mesh pins the jnp ref attention
         # path — the Pallas kernel's scalar-prefetch table lookup is a
         # single-device whole-pool construct (see select_paged_backend)
@@ -110,15 +121,24 @@ class Executor:
         # p_bucket is static: the full-width device table mirror is
         # narrowed to the step's page bucket INSIDE the jit (free), so
         # the host never slices/re-uploads tables per step
+        jit_kw = {}
+        if kv_sharding is not None:
+            # pin the returned pool to the sharding it was placed with:
+            # left to the compiler, an equivalent spec can come back
+            # normalized (P() for size-1 axes) and the next step with
+            # the same bucket would miss the jit cache and recompile
+            layers = cfg.n_layers
+            sc = [] if self._kv_quant is None else [scale_sharding] * layers
+            jit_kw["out_shardings"] = (None, None, [kv_sharding] * layers,
+                                       [kv_sharding] * layers, sc, sc)
         self._step = jax.jit(self._unified_step, static_argnums=(0,),
-                             donate_argnums=(1, 2, 3, 4))
-        self._compiled: set = set()
+                             donate_argnums=(2, 3, 4, 5), **jit_kw)
+        # (t_bucket, p_bucket) of each step that compiled, in order
+        self.compiled_buckets: List[Tuple[int, int]] = []
 
     @property
     def compile_count(self) -> int:
-        if hasattr(self._step, "_cache_size"):
-            return self._step._cache_size()
-        return len(self._compiled)
+        return self._step._cache_size()
 
     # -- host entry -------------------------------------------------------
     def execute(self, plan: StepPlan, kv: PagedKVCache
@@ -134,21 +154,16 @@ class Executor:
         tables = kv.device_tables(plan.slot_seqs, plan.p_bucket)
         ks, vs = kv.take_kv()
         kss, vss = kv.take_scales()      # ([], []) unquantized
-        op = self._place
+        n_compiled = self.compile_count
         try:
             next_tokens, bad, ks, vs, kss, vss = self._step(
-                plan.p_bucket, ks, vs, kss, vss,
-                op(plan.tokens), op(plan.seg_ids),
-                op(plan.positions), op(plan.write_idx),
-                tables, op(plan.sample_idx),
-                op(plan.sample_pos), op(plan.temps),
-                op(plan.top_ks), op(plan.top_ps),
-                op(plan.seeds))
+                *self._operands(plan, tables, ks, vs, kss, vss))
         finally:
             if ks is not None:
                 kv.put_kv(ks, vs)
                 kv.put_scales(kss, vss)
-        self._compiled.add((plan.t_bucket, plan.p_bucket))
+        if self.compile_count > n_compiled:
+            self.compiled_buckets.append((plan.t_bucket, plan.p_bucket))
         return np.asarray(next_tokens), np.asarray(bad)
 
     def _place(self, a) -> jnp.ndarray:
@@ -163,8 +178,26 @@ class Executor:
                 return jax.device_put(a, sh)
         return jnp.asarray(a)
 
+    def _operands(self, plan: StepPlan, tables, ks, vs, kss, vss) -> tuple:
+        """The step's arguments, in ``_unified_step``'s order."""
+        op = self._place
+        return (plan.p_bucket, self._params, ks, vs, kss, vss,
+                op(plan.tokens), op(plan.seg_ids), op(plan.positions),
+                op(plan.write_idx), tables, op(plan.sample_idx),
+                op(plan.sample_pos), op(plan.temps), op(plan.top_ks),
+                op(plan.top_ps), op(plan.seeds))
+
+    def lower(self, plan: StepPlan, kv: PagedKVCache):
+        """Lower (without running) the step for ``plan``'s bucket — what
+        ``execute`` would compile.  Leaves the pool in place."""
+        return self._step.lower(*self._operands(
+            plan, kv.device_tables(plan.slot_seqs, plan.p_bucket),
+            kv.k, kv.v, kv.k_scale or [], kv.v_scale or []))
+
     # -- the jitted data plane -------------------------------------------
-    def _unified_step(self, p_bucket: int, k_pages: List[jnp.ndarray],
+
+    def _unified_step(self, p_bucket: int, params,
+                      k_pages: List[jnp.ndarray],
                       v_pages: List[jnp.ndarray],
                       k_scales: List[jnp.ndarray],
                       v_scales: List[jnp.ndarray],
@@ -177,7 +210,8 @@ class Executor:
                       ) -> Tuple[jnp.ndarray, jnp.ndarray,
                                  List[jnp.ndarray], List[jnp.ndarray],
                                  List[jnp.ndarray], List[jnp.ndarray]]:
-        """Single replica: tokens/seg_ids/positions/write_idx (T,),
+        """``params`` is the :func:`serving_params` tree.
+        Single replica: tokens/seg_ids/positions/write_idx (T,),
         tables (S, W>=P), sample_idx (S, K+1), sample_pos/temps/top_ks/
         top_ps/seeds (S,) — all operands, never statics (per-request
         params cannot trigger a recompile).  With R data replicas every
@@ -194,7 +228,8 @@ class Executor:
         replicated = tokens.ndim == 2
         if not replicated:
             x, new_k, new_v, new_ks, new_vs = self._body(
-                k_pages, v_pages, k_scales, v_scales, tokens, seg_ids,
+                params, k_pages, v_pages, k_scales, v_scales, tokens,
+                seg_ids,
                 positions, write_idx, tables[:, :p_bucket])
             s, kp1 = sample_idx.shape
             xs = jnp.take(x, sample_idx.reshape(-1), axis=0)  # (S*(K+1), D)
@@ -208,8 +243,10 @@ class Executor:
             vs_r = [a.reshape(r, n_local, *a.shape[1:]) for a in v_scales]
             tab_r = tables.reshape(r, tables.shape[0] // r,
                                    tables.shape[1])[:, :, :p_bucket]
-            x, new_k, new_v, new_ks, new_vs = jax.vmap(self._body)(
-                k_r, v_r, ks_r, vs_r, tokens, seg_ids, positions,
+            # weights are shared by every replica (unbatched)
+            x, new_k, new_v, new_ks, new_vs = jax.vmap(
+                self._body, in_axes=(None,) + (0,) * 9)(
+                params, k_r, v_r, ks_r, vs_r, tokens, seg_ids, positions,
                 write_idx, tab_r)
             new_k = [a.reshape(n_total, *a.shape[2:]) for a in new_k]
             new_v = [a.reshape(n_total, *a.shape[2:]) for a in new_v]
@@ -235,8 +272,8 @@ class Executor:
             top_ks = top_ks.reshape(-1)
             top_ps = top_ps.reshape(-1)
             seeds = seeds.reshape(-1)
-        logits = xs @ (self.params["embed"].T if cfg.tie_embeddings
-                       else self.params["lm_head"])
+        logits = xs @ (params["embed"].T if cfg.tie_embeddings
+                       else params["lm_head"])
         # per-slot fault barrier: a NaN/inf logits row (poisoned KV,
         # overflowed activations) flags JUST that slot — the engine
         # quarantines the one request instead of crashing the step loop
@@ -254,7 +291,8 @@ class Executor:
             gen_pos.reshape(-1))
         return toks.reshape(s, kp1), bad, new_k, new_v, new_ks, new_vs
 
-    def _body(self, k_pages: List[jnp.ndarray], v_pages: List[jnp.ndarray],
+    def _body(self, params, k_pages: List[jnp.ndarray],
+              v_pages: List[jnp.ndarray],
               k_scales: List[jnp.ndarray], v_scales: List[jnp.ndarray],
               tokens: jnp.ndarray, seg_ids: jnp.ndarray,
               positions: jnp.ndarray, write_idx: jnp.ndarray,
@@ -271,13 +309,13 @@ class Executor:
         n_pages, ps = k_pages[0].shape[0], k_pages[0].shape[1]
         scale = cfg.query_scale or cfg.hd ** -0.5
 
-        x = jnp.take(self.params["embed"], tokens, axis=0)     # (T, D)
+        x = jnp.take(params["embed"], tokens, axis=0)          # (T, D)
         if cfg.embed_scale:
             x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
 
         qmode = self._kv_quant
         new_k, new_v, new_ks, new_vs = [], [], [], []
-        for li, lp in enumerate(self._layer_params):
+        for li, lp in enumerate(params["layers"]):
             h = L.rms_norm(x, lp["norm1"], cfg.norm_eps, cfg.norm_offset) \
                 if cfg.norm == "rms" else L.layer_norm(
                     x, lp["norm1"], lp.get("norm1_b"), cfg.norm_eps)
@@ -337,8 +375,8 @@ class Executor:
                                       cfg.norm_eps)
                 x = x + L.mlp(lp["mlp"], h2, cfg.act)
 
-        x = L.rms_norm(x, self.params["final_norm"], cfg.norm_eps,
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps,
                        cfg.norm_offset) if cfg.norm == "rms" else \
-            L.layer_norm(x, self.params["final_norm"],
-                         self.params.get("final_norm_b"), cfg.norm_eps)
+            L.layer_norm(x, params["final_norm"],
+                         params.get("final_norm_b"), cfg.norm_eps)
         return x, new_k, new_v, new_ks, new_vs

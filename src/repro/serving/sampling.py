@@ -131,10 +131,11 @@ def sample_tokens(logits: jnp.ndarray, temperature: jnp.ndarray,
     ``temperature``/``top_k``/``top_p``/``seeds``/``positions`` are
     ``(R,)`` per-row arrays (operands, not statics — per-request params
     never trigger a recompile).  Stochastic rows draw via the
-    Gumbel-max trick over the filtered support (one fused perturb
-    kernel on TPU, see ``kernels.ops.gumbel_perturb``); rows with
-    ``temperature <= 0`` return plain ``argmax(logits)``.  Returns
-    ``(R,)`` int32 token ids."""
+    Gumbel-max trick over the filtered support (``logits +
+    (-log(-log(u)))``, one elementwise pass XLA fuses, and one that
+    GSPMD partitions under a mesh); rows with ``temperature <= 0``
+    return plain ``argmax(logits)``.  Returns ``(R,)`` int32 token
+    ids."""
     logits = logits.astype(jnp.float32)
     v = logits.shape[-1]
     filtered = jax.vmap(filter_logits)(logits, temperature, top_k, top_p)
@@ -143,8 +144,7 @@ def sample_tokens(logits: jnp.ndarray, temperature: jnp.ndarray,
         lambda k: jax.random.uniform(k, (v,), jnp.float32,
                                      minval=_MIN_UNIFORM)
     )(keys)
-    from ..kernels import ops as kops
-    perturbed = kops.gumbel_perturb(filtered, uniform)
+    perturbed = filtered + -jnp.log(-jnp.log(uniform))
     stochastic = jnp.argmax(perturbed, axis=-1)
     greedy = jnp.argmax(logits, axis=-1)
     return jnp.where(temperature > 0.0, stochastic,

@@ -18,13 +18,18 @@ from repro.checkpoint import CheckpointManager
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
+# meshes in the snippets below take Auto axes: the sharding rules
+# annotate and let GSPMD propagate (jax.make_mesh defaults to Explicit)
+PREAMBLE = "from jax.sharding import AxisType\nAUTO = AxisType.Auto\n"
+
 
 def run_subprocess(code: str, n_devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{n_devices}")
     env["PYTHONPATH"] = SRC
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+    out = subprocess.run([sys.executable, "-c",
+                          PREAMBLE + textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
                          timeout=540)
     assert out.returncode == 0, out.stderr[-3000:]
@@ -73,13 +78,13 @@ class TestCheckpoint:
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.checkpoint import CheckpointManager
             mesh4 = jax.make_mesh((4,), ("data",),
-                devices=jax.devices()[:4])
+                devices=jax.devices()[:4], axis_types=(AUTO,))
             w = jax.device_put(jnp.arange(32.0).reshape(8, 4),
                                NamedSharding(mesh4, P("data", None)))
             mgr = CheckpointManager(r"{tmp_path}")
             mgr.save({{"w": w}}, 1)
 
-            mesh8 = jax.make_mesh((8,), ("data",))
+            mesh8 = jax.make_mesh((8,), ("data",), axis_types=(AUTO,))
             like = jax.device_put(jnp.zeros((8, 4)),
                                   NamedSharding(mesh8, P("data", None)))
             restored = mgr.restore(1, {{"w": like}}, mesh8)
@@ -100,7 +105,8 @@ class TestShardingRules:
             from repro.configs import ARCHS, get_config
             from repro.models.lm import abstract_params
             from repro.distributed.sharding import param_specs
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = jax.make_mesh((2, 4), ("data", "model"),
+                                 axis_types=(AUTO,) * 2)
             for arch in ARCHS:
                 cfg = get_config(arch)
                 ap = abstract_params(cfg)
@@ -131,7 +137,8 @@ class TestShardingRules:
             from repro.launch.train import make_train_step
             from repro.models.lm import init_params
             from repro.optim.functional import make_optimizer
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = jax.make_mesh((4, 2), ("data", "model"),
+                                 axis_types=(AUTO,) * 2)
             cfg = get_smoke_config("gemma-2b")
             batch_abs = {
                 "tokens": jax.ShapeDtypeStruct((8, 16), jnp.int32),
@@ -170,7 +177,8 @@ class TestShardingRules:
             from repro.launch.train import make_train_step
             from repro.models.lm import init_params
             from repro.optim.functional import make_optimizer
-            mesh = jax.make_mesh((2, 1), ("data", "model"))
+            mesh = jax.make_mesh((2, 1), ("data", "model"),
+                                 axis_types=(AUTO,) * 2)
             cfg = get_smoke_config("yi-34b")
             batch_abs = {
                 "tokens": jax.ShapeDtypeStruct((8, 8), jnp.int32),
@@ -217,7 +225,7 @@ class TestDDPAndPipeline:
             import repro.nn.functional as F
             from repro.distributed.ddp import DistributedDataParallel
             from repro.distributed.pipeline import pipeline_apply
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = jax.make_mesh((8,), ("data",), axis_types=(AUTO,))
             m = nn.Sequential(nn.Linear(16, 32), nn.ReLU(),
                               nn.Linear(32, 4))
             ddp = DistributedDataParallel(m, mesh=mesh, bucket_mb=1e-4)
@@ -232,7 +240,7 @@ class TestDDPAndPipeline:
             assert ddp.stats["num_allreduce"] >= 2
             print("DDP_OK")
 
-            mesh_p = jax.make_mesh((8,), ("pod",))
+            mesh_p = jax.make_mesh((8,), ("pod",), axis_types=(AUTO,))
             ws = jax.random.normal(jax.random.key(0), (8, 16, 16)) * 0.1
             out = pipeline_apply(
                 lambda w, x: jnp.tanh(x @ w["w"]), {"w": ws},

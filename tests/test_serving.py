@@ -1011,6 +1011,32 @@ class TestDonationInvariant:
         assert ks2 is not None
 
 
+class TestStepOperands:
+    def test_weights_are_arguments_not_constants(self):
+        """The jitted step takes every weight as an operand: a closure
+        constant would bake the model into the module (past the 2 GB
+        protobuf limit at gemma-2b width) and into the cache key."""
+        cfg = tiny_cfg()
+        eng = ServingEngine(cfg, init_params(cfg, jax.random.key(0)),
+                            page_size=4, num_pages=16, max_batch=2,
+                            chunk_size=8)
+        eng.submit([1, 2, 3, 4, 5], max_new_tokens=2)
+        lowered = eng.executor.lower(eng.scheduler.plan(), eng.kv)
+        weights = jax.tree_util.tree_leaves(eng.executor._params)
+        passed = jax.tree_util.tree_leaves(lowered.args_info[0][0])
+        assert [(a.shape, a.dtype) for a in passed] == \
+            [(w.shape, w.dtype) for w in weights]
+        text = lowered.as_text()
+        consts = [ln for ln in text.splitlines()
+                  if "stablehlo.constant" in ln]
+        for w in weights:
+            if w.ndim < 2:
+                continue
+            ty = "x".join(map(str, w.shape)) + "x" + \
+                {"float32": "f32", "bfloat16": "bf16"}[w.dtype.name]
+            assert not any(f"tensor<{ty}>" in ln for ln in consts), ty
+
+
 class TestPagePoolProperties:
     def test_alloc_free_invariants_random_trace(self):
         """Property: under random alloc/retain/release traces the pool
@@ -1494,7 +1520,9 @@ class TestShardedParity:
             params = init_params(cfg, jax.random.key(0))
 
             def run(shape):
-                mesh = (jax.make_mesh(shape, ("data", "model"))
+                mesh = (jax.make_mesh(
+                            shape, ("data", "model"),
+                            axis_types=(jax.sharding.AxisType.Auto,) * 2)
                         if shape else None)
                 eng = ServingEngine(
                     cfg, params, page_size=4, num_pages=64, max_batch=4,
@@ -1544,7 +1572,9 @@ class TestShardedParity:
             ref = paged_attention(q, kp, vp, tables, seg, pos,
                                   backend="ref")
 
-            mesh = jax.make_mesh((1, 2), ("data", "model"))
+            mesh = jax.make_mesh(
+                (1, 2), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
             kv_sh = NamedSharding(mesh, P(None, None, "model", None))
             f = jax.jit(lambda *a: paged_attention(*a, backend="ref"))
             got = f(q, jax.device_put(kp, kv_sh),
