@@ -420,7 +420,7 @@ def bench_kernels() -> None:
     t_ker = timeit(lambda: f_ker(q, k, v).block_until_ready(), iters=3)
     emit("kernels/flash_ref_jnp", t_ref, "XLA-fused reference")
     emit("kernels/flash_pallas_interpret", t_ker,
-         "interpret mode (CPU emulation; TPU perf via roofline)")
+         "interpret mode (CPU emulation, not a TPU time)")
 
 
 def _serve_with_outputs(eng, round_idx: int):

@@ -10,50 +10,20 @@ Sections:
                 §5.5 refcount memory, §5.4 dataloader transport
   serving     — scheduler/executor engine vs the legacy monolith on the
                 mixed workload + kernel wall-times (CPU interpret)
-  roofline    — summarizes experiments/dryrun/*.json (produced by
-                ``python -m repro.launch.dryrun --all``) — the TPU-side
-                performance story lives there.
 
 Output: ``name,us_per_call,derived`` CSV on stdout.
 """
 
 import argparse
-import glob
-import json
-import os
 
-from .common import emit, header
-
-
-def roofline_summary() -> None:
-    pattern = os.path.join(os.path.dirname(__file__), "..",
-                           "experiments", "dryrun", "*.json")
-    files = sorted(glob.glob(pattern))
-    if not files:
-        print("# roofline: no dry-run artifacts found "
-              "(run python -m repro.launch.dryrun --all)", flush=True)
-        return
-    for f in files:
-        with open(f) as fh:
-            rec = json.load(fh)
-        if rec.get("status") != "ok" or rec.get("mesh") != "single":
-            continue   # multi-pod cells skip the unrolled cost pass
-        rl = rec["roofline"]
-        total = max(rl["compute_s"], rl["memory_s"], rl["collective_s"])
-        emit(f"roofline/{rec['arch']}/{rec['shape']}/{rec['mesh']}",
-             total,
-             f"dominant={rl['dominant']} "
-             f"compute={rl['compute_s']*1e3:.2f}ms "
-             f"memory={rl['memory_s']*1e3:.2f}ms "
-             f"collective={rl['collective_s']*1e3:.2f}ms "
-             f"useful={rl['useful_ratio']:.2f}")
+from .common import header
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", default=True)
     ap.add_argument("--sections",
-                    default="table1,dispatch,runtime,serving,roofline")
+                    default="table1,dispatch,runtime,serving")
     args = ap.parse_args()
     sections = set(args.sections.split(","))
 
@@ -70,8 +40,6 @@ def main() -> None:
     if "serving" in sections:
         from . import bench_serving
         bench_serving.run(quick=args.quick)
-    if "roofline" in sections:
-        roofline_summary()
 
 
 if __name__ == "__main__":

@@ -1,13 +1,7 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Defined as functions (never module-level constants) so importing this
-module never touches JAX device state — the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any JAX
-import and only then calls ``make_production_mesh``.
-
-Mesh shapes:
-  single pod:  (data=16, model=16)          — 256 chips (one v5e pod)
-  multi-pod:   (pod=2, data=16, model=16)   — 512 chips across DCN
+module never touches JAX device state.
 
 Axis roles:
   pod   — pure data parallelism across pods (DCN-crossing collectives are
@@ -23,12 +17,6 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 from jax.sharding import AxisType
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]

@@ -3,7 +3,7 @@
     PYTHONPATH=src python -m repro.launch.server [--preset tiny|small |
         --config ARCH] [--seed S]
         [--host 127.0.0.1] [--port 8008] [--num-pages N]
-        [--hwm-frac F] [--max-stream-tokens N] [--selftest N]
+        [--hwm-frac F] [--max-stream-tokens N] [--trace] [--selftest N]
 
 A dependency-free asyncio HTTP server (``asyncio.start_server`` — no
 aiohttp in the container) over :class:`~repro.serving.AsyncFrontend`.
@@ -24,6 +24,7 @@ Routes::
                         Retry-After header; other admission rejections
                         -> 429; bad JSON -> 400.
     GET  /metrics    engine + frontend counters as JSON
+    GET  /trace      the tracer's spans in memory as JSON (``--trace``)
     GET  /healthz    200 "ok"
 
 Disconnect semantics: if the client drops mid-stream the write fails,
@@ -124,6 +125,12 @@ class HttpFrontendServer:
                 writer.write(_response(
                     "200 OK", {"Content-Type": "application/json"},
                     payload))
+            elif method == "GET" and path == "/trace":
+                payload = json.dumps(
+                    self.frontend.engine.tracer.records()).encode()
+                writer.write(_response(
+                    "200 OK", {"Content-Type": "application/json"},
+                    payload))
             elif method == "POST" and path == "/generate":
                 await self._generate(writer, body)
             else:
@@ -176,6 +183,7 @@ class HttpFrontendServer:
                       "Content-Type: text/event-stream\r\n"
                       "Cache-Control: no-cache\r\n"
                       "Connection: close\r\n\r\n").encode())
+        tr = self.frontend.engine.tracer
         try:
             ev = first
             while True:
@@ -184,9 +192,19 @@ class HttpFrontendServer:
                         "req_id": ev.req_id, "error": ev.error}))
                     await writer.drain()
                     return
-                writer.write(_sse("token", {
-                    "token": ev.token, "index": ev.index}))
-                await writer.drain()   # raises when the client is gone
+                # the write span crosses an await: it parents nothing,
+                # and it ends however the write ends
+                op = None
+                if tr.enabled and ev.committed_at is not None:
+                    op = tr.begin("server.write", req_id=ev.req_id,
+                                  nest=False)
+                try:
+                    writer.write(_sse("token", {
+                        "token": ev.token, "index": ev.index}))
+                    await writer.drain()   # raises when the client is gone
+                finally:
+                    if op is not None:
+                        tr.end(op, committed=ev.committed_at)
                 ev = await stream.__anext__()
         finally:
             # disconnect or server shutdown: abandoning the generator
@@ -290,6 +308,8 @@ def main() -> None:
     ap.add_argument("--max-stream-tokens", type=int, default=256,
                     help="hard cap on any one request's token budget")
     ap.add_argument("--max-queue-depth", type=int, default=64)
+    ap.add_argument("--trace", action="store_true",
+                    help="record the serving path's spans (GET /trace)")
     ap.add_argument("--selftest", type=int, default=None, metavar="N",
                     help="serve N requests through a real socket on an "
                          "ephemeral port, print metrics, and exit")
@@ -299,6 +319,7 @@ def main() -> None:
     eng = build_engine(args.preset, args.config, seed=args.seed,
                        num_pages=args.num_pages, page_size=args.page_size,
                        max_batch=args.max_batch, chunk_size=args.chunk)
+    eng.tracer.enabled = args.trace
     fe = AsyncFrontend(eng, hwm_frac=args.hwm_frac,
                        max_queue_depth=args.max_queue_depth,
                        max_stream_tokens=args.max_stream_tokens)

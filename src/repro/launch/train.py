@@ -90,8 +90,8 @@ def make_train_step(cfg: LM.LMConfig, mesh: Mesh, *,
                     opt_kwargs: Optional[Dict] = None):
     """Returns (train_step_jit, state_shardings, abstract_state,
     batch_shardings_fn).  Pass ``batch_abs`` (ShapeDtypeStructs) so the
-    batch input shardings are pinned at jit time (required for the
-    dry-run's .lower()).
+    batch input shardings are pinned at jit time (required to
+    ``.lower()`` against abstract inputs).
 
     ``foreach=True`` selects the fused multi-tensor optimizer update
     (bucketed concat, one kernel per dtype bucket) — fewer HLO ops and
@@ -170,20 +170,6 @@ def make_train_step(cfg: LM.LMConfig, mesh: Mesh, *,
         donate_argnums=(0,) if donate else (),
     )
     return jit_step, state_shardings, state_abs, batch_shardings
-
-
-def make_prefill_step(cfg: LM.LMConfig, mesh: Mesh):
-    params_abs = LM.abstract_params(cfg)
-    p_shardings = shard_tree(mesh, S.param_specs(cfg, params_abs, mesh))
-
-    def prefill(params, batch):
-        with AS.scope(mesh):
-            logits, _ = LM.forward(cfg, params, tokens=batch.get("tokens"),
-                                   embeds=batch.get("embeds"))
-        return logits
-
-    jit_step = jax.jit(prefill, in_shardings=(p_shardings, None))
-    return jit_step, p_shardings, params_abs
 
 
 def make_serve_step(cfg: LM.LMConfig, mesh: Mesh, *, batch: int,

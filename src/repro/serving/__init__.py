@@ -6,7 +6,8 @@ temperature / top-k / top-p), speculative-decoding proposers
 ``errors``, the invariant ``watchdog``, and the deterministic
 ``faults`` injection harness.  The asyncio streaming front door
 (``frontend``) bridges per-token streams, mid-stream cancellation and
-watermark backpressure onto the engine loop."""
+watermark backpressure onto the engine loop; ``tracing`` holds the
+spans and counters of that path (off unless enabled)."""
 
 from . import errors
 from .engine import ServingEngine
@@ -22,6 +23,7 @@ from .sampling import SamplingParams
 from .scheduler import Request, RequestState, Scheduler, StepPlan
 from .spec import (DraftModelProposer, FixedProposer, NgramProposer,
                    Proposer)
+from .tracing import Tracer
 from .watchdog import Violation, Watchdog
 
 __all__ = ["ServingEngine", "LegacyServingEngine", "PagedKVCache",
@@ -32,4 +34,4 @@ __all__ = ["ServingEngine", "LegacyServingEngine", "PagedKVCache",
            "RequestFailed", "FaultInjected", "FaultInjector",
            "FaultSpec", "Watchdog", "Violation", "SamplingParams",
            "Proposer", "NgramProposer", "DraftModelProposer",
-           "FixedProposer"]
+           "FixedProposer", "Tracer"]

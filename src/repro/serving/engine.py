@@ -59,6 +59,7 @@ from .kv_cache import PagedKVCache
 from .sampling import SamplingParams
 from .scheduler import Request, RequestState, Scheduler
 from .spec import NgramProposer, Proposer
+from .tracing import OFF, Tracer
 from .watchdog import Watchdog
 
 __all__ = ["ServingEngine", "Request", "RequestState"]
@@ -88,7 +89,8 @@ class ServingEngine:
                  faults: Optional[FaultInjector] = None,
                  mesh=None, n_replicas: int = 1,
                  kv_dtype: Optional[str] = None,
-                 clock: Callable[[], float] = time.perf_counter):
+                 clock: Callable[[], float] = time.perf_counter,
+                 tracer: Optional[Tracer] = None):
         for spec in cfg.pattern:
             if spec.mixer not in ("attn",):
                 raise ValueError(
@@ -157,11 +159,17 @@ class ServingEngine:
         # size the device table mirror at the pages bucket cap up front:
         # the delta path then never pays a width-growth rebuild
         self.kv.mirror_width_hint = self.scheduler.p_buckets()[-1]
+        # spans of the serving path (``serving.tracing``);
+        # off unless a caller sets ``tracer.enabled``
+        self.tracer = tracer if tracer is not None else Tracer(clock)
+        # end of the latest traced commit: stamps the tokens it made
+        self.last_commit_end: Optional[float] = None
         self.executor = Executor(cfg, params, mesh=mesh,
                                  n_replicas=n_replicas,
                                  kv_sharding=kv_sharding,
                                  kv_quant=self.kv.quant_mode,
-                                 scale_sharding=scale_sharding)
+                                 scale_sharding=scale_sharding,
+                                 tracer=self.tracer)
         self.watchdog = Watchdog(interval=watchdog_interval,
                                  stall_steps=stall_steps)
         # fault injection: ctor arg, else env (None = zero overhead)
@@ -257,10 +265,19 @@ class ServingEngine:
         boundary treated as a fault line; any other executor exception
         propagates.  None = nothing runnable."""
         self._step_no += 1
+        tr = self.tracer
+        if not tr.enabled:
+            return self._run_step(tr)
+        tr.step = self._step_no
+        with tr.span("engine.step"):
+            return self._run_step(tr)
+
+    def _run_step(self, tr: Tracer) -> Optional[List[Request]]:
         if self.faults is not None:
             self.faults.before_plan(self._step_no, self.scheduler,
                                     self.kv)
-        plan = self.scheduler.plan()
+        with tr.span("scheduler.plan") if tr.enabled else OFF:
+            plan = self.scheduler.plan()
         if plan is None:
             return None
         try:
@@ -288,7 +305,10 @@ class ServingEngine:
                     self._quarantine(s.req.req_id,
                                      "non-finite logits (executor "
                                      "fault barrier)")
-        done = self.scheduler.commit(plan, next_tokens)
+        with tr.span("scheduler.commit") if tr.enabled else OFF as op:
+            done = self.scheduler.commit(plan, next_tokens)
+        if op is not None:
+            self.last_commit_end = op.end
         if self.watchdog.due(self._step_no):
             self._run_watchdog()
         return done

@@ -39,7 +39,7 @@ from __future__ import annotations
 
 import math
 import warnings
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +51,7 @@ from ..models import lm as LM
 from . import quant, sampling
 from .kv_cache import PagedKVCache
 from .scheduler import StepPlan
+from .tracing import OFF, Tracer
 
 # buffer donation is a TPU/GPU optimization; CPU (tests) just warns
 warnings.filterwarnings(
@@ -84,8 +85,10 @@ class Executor:
 
     def __init__(self, cfg: LM.LMConfig, params, *, mesh=None,
                  n_replicas: int = 1, kv_sharding=None,
-                 kv_quant=None, scale_sharding=None):
+                 kv_quant=None, scale_sharding=None,
+                 tracer: Optional[Tracer] = None):
         self.cfg = cfg
+        self.tracer = tracer if tracer is not None else Tracer()
         # quantized KV: the step quantizes k/v per (token, head) right
         # before the flat scatter (codes into the pool, scales into the
         # parallel arrays at the SAME write_idx) and attention
@@ -151,20 +154,29 @@ class Executor:
         without losing the step for everyone else).  Sampling runs
         INSIDE the jit: only these two small arrays ever cross the
         device boundary — the (S·(K+1), V) logits never do."""
-        tables = kv.device_tables(plan.slot_seqs, plan.p_bucket)
-        ks, vs = kv.take_kv()
-        kss, vss = kv.take_scales()      # ([], []) unquantized
+        tr = self.tracer
+        t0 = tr.clock() if tr.enabled else 0.0
+        with tr.span("executor.prepare") if tr.enabled else OFF:
+            tables = kv.device_tables(plan.slot_seqs, plan.p_bucket)
+            ks, vs = kv.take_kv()
+            kss, vss = kv.take_scales()      # ([], []) unquantized
         n_compiled = self.compile_count
         try:
-            next_tokens, bad, ks, vs, kss, vss = self._step(
-                *self._operands(plan, tables, ks, vs, kss, vss))
+            # the operands are placed inside the call's span
+            with tr.span("executor.dispatch") if tr.enabled else OFF:
+                next_tokens, bad, ks, vs, kss, vss = self._step(
+                    *self._operands(plan, tables, ks, vs, kss, vss))
         finally:
             if ks is not None:
                 kv.put_kv(ks, vs)
                 kv.put_scales(kss, vss)
         if self.compile_count > n_compiled:
             self.compiled_buckets.append((plan.t_bucket, plan.p_bucket))
-        return np.asarray(next_tokens), np.asarray(bad)
+            if tr.enabled:
+                tr.record("executor.build", t0, tr.clock(),
+                          t_bucket=plan.t_bucket, p_bucket=plan.p_bucket)
+        with tr.span("executor.wait") if tr.enabled else OFF:
+            return np.asarray(next_tokens), np.asarray(bad)
 
     def _place(self, a) -> jnp.ndarray:
         """Plan operands under a mesh get an explicit replica-axis

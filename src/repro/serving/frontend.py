@@ -62,12 +62,15 @@ class StreamEvent:
     value — ``"finished"``, ``"cancelled"``, ``"timed_out"``,
     ``"failed"`` — with ``error`` carrying the retirement reason.  A
     stream yields zero or more token events and exactly one terminal
-    event."""
+    event.  With the engine's tracer on, a token event carries
+    ``committed_at``: the end, on the engine's clock, of the commit
+    that produced it."""
     kind: str
     req_id: int
     token: Optional[int] = None
     index: int = -1
     error: Optional[str] = None
+    committed_at: Optional[float] = None
 
     @property
     def terminal(self) -> bool:
@@ -217,6 +220,12 @@ class AsyncFrontend:
         it directly for deterministic interleaving; :meth:`run` wraps
         it for real servers."""
         self.engine.step()
+        tr = self.engine.tracer
+        committed = op = None
+        if tr.enabled:
+            # every token found below was committed by that step
+            committed = self.engine.last_commit_end
+            op = tr.begin("frontend.fanout", nest=False)
         events = 0
         for rid, st in list(self._streams.items()):
             if st.closed:
@@ -228,7 +237,7 @@ class AsyncFrontend:
             while st.delivered < len(out):
                 st.queue.put_nowait(StreamEvent(
                     "token", rid, token=out[st.delivered],
-                    index=st.delivered))
+                    index=st.delivered, committed_at=committed))
                 st.delivered += 1
                 self.metrics["tokens_streamed"] += 1
                 events += 1
@@ -241,6 +250,8 @@ class AsyncFrontend:
                     self.metrics["streams_finished"] += 1
                 else:
                     self.metrics["streams_aborted"] += 1
+        if op is not None:
+            tr.end(op)
         return events
 
     @property

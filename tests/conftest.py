@@ -3,14 +3,14 @@ import random
 import sys
 import types
 
-# smoke tests and benches must see the single real CPU device — the
-# 512-device flag belongs ONLY to the dry-run entry point.  Exception:
-# the multi-device CI job (sharded serving) opts in explicitly with
-# REPRO_ALLOW_MULTIDEVICE=1 + a SMALL forced device count.
+# smoke tests and benches must see the single real CPU device.
+# Exception: the multi-device CI job (sharded serving) opts in
+# explicitly with REPRO_ALLOW_MULTIDEVICE=1 + a SMALL forced device
+# count.
 assert os.environ.get("REPRO_ALLOW_MULTIDEVICE") == "1" or \
     "xla_force_host_platform_device_count" not in \
     os.environ.get("XLA_FLAGS", ""), \
-    "do not set the dry-run XLA_FLAGS globally " \
+    "do not force a host device count globally " \
     "(REPRO_ALLOW_MULTIDEVICE=1 overrides for the multi-device CI job)"
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
