@@ -215,8 +215,9 @@ class Served:
     trace_s: Optional[Tuple[float, float]] = None
 
 
-async def serve(engine, traffic: dict, reqs, seconds: float,
-                 trace_dir: Optional[Path], log: Log) -> Served:
+async def serve(engine, traffic: dict, seed: int, vocab: int,
+                seconds: float, trace_dir: Optional[Path],
+                log: Log) -> Served:
     import jax
     from repro.launch.server import HttpFrontendServer
     from repro.serving.frontend import AsyncFrontend
@@ -227,13 +228,10 @@ async def serve(engine, traffic: dict, reqs, seconds: float,
     instrument(engine, fe, log)
     server = HttpFrontendServer(fe, HOST, 0)
     await server.start()
-    plan = {"host": HOST, "port": server.port, "loop": traffic["loop"],
-            "clients": traffic.get("clients", 0),
+    plan = {"host": HOST, "port": server.port,
             "warmup_s": traffic["warmup_s"], "window_s": seconds,
-            "drain_s": traffic["drain_s"],
-            "requests": [{"prompt": r.prompt,
-                          "max_new_tokens": r.max_new_tokens, "at": r.at}
-                         for r in reqs]}
+            "drain_s": traffic["drain_s"], "traffic": traffic,
+            "seed": seed, "vocab": vocab}
     proc = await asyncio.create_subprocess_exec(
         sys.executable, str(LOADGEN), stdin=asyncio.subprocess.PIPE,
         stdout=asyncio.subprocess.PIPE, limit=1 << 30)
@@ -376,13 +374,15 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     buckets = reachable_buckets(engine, config, traffic)
     warm(engine, buckets)
     say(f"warmed {len(buckets)} (tokens, pages) buckets")
-    reqs = traffic_mod.generate(traffic, seed, config["vocab_size"],
-                                seconds)
     trace_dir = work_dir / "trace" if trace else None
-    served = asyncio.run(serve(engine, traffic, reqs, seconds,
-                                trace_dir, log))
+    served = asyncio.run(serve(engine, traffic, seed, config["vocab_size"],
+                               seconds, trace_dir, log))
     res = served.result
     records = res["records"]
+    # the requests the load generator drew, drawn again from the seed
+    reqs = traffic_mod.generate(
+        traffic, seed, config["vocab_size"], seconds,
+        count=1 + max((r["i"] for r in records), default=-1))
     for r in records:
         r["max_new_tokens"] = reqs[r["i"]].max_new_tokens
     window = tuple(res["window"])
@@ -434,6 +434,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
            "metrics": metrics, "device": device, **extra,
            "setup": {"setup_s": setup_s, "buckets_warmed": len(buckets)},
            "loadgen": stats.lateness(records, window),
+           "requests_sent": len(records),
            "steps_in_window": steps_by_bucket(steps),
            "readings": {k: v for k, v in values.items()
                         if k != "logit_gap"}}

@@ -2,21 +2,23 @@
 
     python loadgen.py < plan.json
 
-Imports nothing but the standard library (never JAX: the chip belongs
-to the server's process), and takes every timestamp on its own clock,
-so a server that stalls its event loop cannot hide the stall from the
-client's numbers.  The plan on standard input::
+Imports the standard library and ``traffic.py`` beside it (numpy;
+never JAX: the chip belongs to the server's process), and takes every
+timestamp on its own clock, so a server that stalls its event loop
+cannot hide the stall from the client's numbers.  The plan on standard
+input::
 
-    {"host": "127.0.0.1", "port": 8008, "loop": "open" | "closed",
-     "clients": 16, "warmup_s": 10, "window_s": 30, "drain_s": 60,
-     "requests": [{"prompt": [...], "max_new_tokens": 64, "at": 0.25},
-                  ...]}
+    {"host": "127.0.0.1", "port": 8008, "warmup_s": 10, "window_s": 30,
+     "drain_s": 60, "traffic": {<the traffic file>}, "seed": 7,
+     "vocab": 32000}
 
-Open loop: request ``i`` is due at ``at`` seconds after the start; it is
-sent then, late if the generator fell behind.  Closed loop: ``clients``
-clients each send their next request when the last one ends.  Requests
-are sent from the start until the window closes (``warmup_s +
-window_s``); then the requests in flight get ``drain_s`` seconds to end.
+The requests are ``traffic.requests(traffic, seed, vocab, window_s)``,
+drawn as they are sent.  Open loop: request ``i`` is due at ``at``
+seconds after the start; it is sent then, late if the generator fell
+behind.  Closed loop: ``clients`` clients each send their next request
+when the last one ends, and never run out.  Requests are sent from the
+start until the window closes (``warmup_s + window_s``); then the
+requests in flight get ``drain_s`` seconds to end.
 
 Standard output carries ``WINDOW_START <unix time>`` and ``WINDOW_END
 <unix time>`` as the window opens and closes, then one line ``RESULT
@@ -33,6 +35,8 @@ import json
 import sys
 import time
 from typing import Dict, List
+
+import traffic
 
 
 async def _stream(host: str, port: int, spec: dict, rec: dict,
@@ -89,7 +93,10 @@ def _record(i: int, due: float) -> dict:
 async def run(plan: dict, out=sys.stdout) -> dict:
     """Drive the plan; return the result written after ``RESULT``."""
     host, port = plan["host"], int(plan["port"])
-    reqs = plan["requests"]
+    mix = plan["traffic"]
+    reqs = enumerate(traffic.requests(mix, int(plan["seed"]),
+                                      int(plan["vocab"]),
+                                      float(plan["window_s"])))
     t0 = time.perf_counter()
 
     def clock() -> float:
@@ -100,13 +107,12 @@ async def run(plan: dict, out=sys.stdout) -> dict:
     records: List[dict] = []
     tasks: Dict[int, asyncio.Task] = {}
 
-    async def one(i: int, due: float) -> None:
+    async def one(i: int, due: float, r: traffic.Request) -> None:
         rec = _record(i, due)
         records.append(rec)
-        r = reqs[i]
         try:
-            await _stream(host, port, {"prompt": r["prompt"],
-                                       "max_new_tokens": r["max_new_tokens"]},
+            await _stream(host, port, {"prompt": r.prompt,
+                                       "max_new_tokens": r.max_new_tokens},
                           rec, clock)
         except (ConnectionError, OSError, ValueError,
                 asyncio.IncompleteReadError) as e:
@@ -122,25 +128,18 @@ async def run(plan: dict, out=sys.stdout) -> dict:
         out.flush()
 
     async def open_loop() -> None:
-        for i, r in enumerate(reqs):
-            if r["at"] >= w_end:
+        for i, r in reqs:
+            if r.at >= w_end:
                 break
-            delay = r["at"] - clock()
+            delay = r.at - clock()
             if delay > 0:
                 await asyncio.sleep(delay)
-            tasks[i] = asyncio.ensure_future(one(i, r["at"]))
-
-    nxt = [0]
+            tasks[i] = asyncio.ensure_future(one(i, r.at, r))
 
     async def client() -> None:
         while clock() < w_end:
-            i = nxt[0]
-            if i >= len(reqs):
-                raise RuntimeError(
-                    f"closed loop ran out of its {len(reqs)} requests; "
-                    f"raise the traffic file's 'requests'")
-            nxt[0] += 1
-            task = asyncio.ensure_future(one(i, clock()))
+            i, r = next(reqs)
+            task = asyncio.ensure_future(one(i, clock(), r))
             tasks[i] = task
             # at the window's end the client stops; its request in
             # flight is left to the drain below
@@ -149,11 +148,11 @@ async def run(plan: dict, out=sys.stdout) -> dict:
                 return
 
     mark_task = asyncio.ensure_future(marks())
-    if plan["loop"] == "open":
+    if mix["loop"] == "open":
         senders = [asyncio.ensure_future(open_loop())]
     else:
         senders = [asyncio.ensure_future(client())
-                   for _ in range(int(plan["clients"]))]
+                   for _ in range(int(mix["clients"]))]
     await asyncio.gather(mark_task, *senders)
     # every client stopped sending at the window's end; the requests in
     # flight get drain_s to finish, and a request that never ends is

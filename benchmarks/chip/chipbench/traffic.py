@@ -17,7 +17,6 @@ prefix.
 A traffic file::
 
     {"loop": "closed", "clients": 16,          # or "open", "rate_per_s"
-     "requests": 128,                          # closed loop: how many
      "prompt_tokens": {"dist": "uniform", "min": 4096, "max": 12288},
      "output_tokens": {"dist": "lognormal", "median": 128,
                        "sigma": 0.8, "min": 16, "max": 512},
@@ -27,10 +26,11 @@ A traffic file::
 
 from __future__ import annotations
 
+import itertools
 import math
 from dataclasses import dataclass
 from statistics import NormalDist
-from typing import List
+from typing import Iterator, List, Optional
 
 import numpy as np
 
@@ -94,15 +94,17 @@ def _arrivals(n: int, rate: float, start: float, rng) -> np.ndarray:
         gaps[rng.permutation(n)])[:-1]])
 
 
-def generate(traffic: dict, seed: int, vocab: int,
-             seconds: float) -> List[Request]:
+def requests(traffic: dict, seed: int, vocab: int,
+             seconds: float) -> Iterator[Request]:
     """The run's requests, in sending order, for ``seed``.
 
     Open loop: ``rate_per_s * warmup_s`` warm-up requests due from 0,
     then ``rate_per_s * seconds`` window requests due from ``warmup_s``:
     two multisets of their own, so the window's sizes and count are the
-    same for every seed.  Closed loop: ``requests`` requests (rounded up
-    to waves of ``clients``) that the clients take in order."""
+    same for every seed.  Closed loop: waves of ``clients`` requests
+    without end, drawn as the clients take them, so a faster program
+    never empties the loop; wave ``k`` is the same whoever stops after
+    it."""
     rng = np.random.default_rng(seed)
     order = np.random.default_rng(ORDER_SEED)
     if traffic["loop"] == "open":
@@ -114,17 +116,25 @@ def generate(traffic: dict, seed: int, vocab: int,
         # waves of one request per client, each wave the same multiset
         # of sizes: the clients' first requests, sent at once, carry the
         # same work for every seed
-        c = int(traffic["clients"])
-        parts = [(c, None)] * -(-int(traffic["requests"]) // c)
+        parts = itertools.repeat((int(traffic["clients"]), None))
     else:
         raise ValueError(f"loop must be open or closed, "
                          f"not {traffic['loop']!r}")
-    out: List[Request] = []
     for n, start in parts:
         prompts, outputs = _lengths(traffic, n, order)
         at = (np.zeros(n) if start is None
               else _arrivals(n, rate, start, order))
-        out += [Request(prompt=rng.integers(1, vocab, p).tolist(),
-                        max_new_tokens=o, at=float(t))
-                for p, o, t in zip(prompts, outputs, at)]
-    return out
+        for p, o, t in zip(prompts, outputs, at):
+            yield Request(prompt=rng.integers(1, vocab, p).tolist(),
+                          max_new_tokens=o, at=float(t))
+
+
+def generate(traffic: dict, seed: int, vocab: int, seconds: float,
+             count: Optional[int] = None) -> List[Request]:
+    """The first ``count`` of the run's requests; all of an open loop's
+    where ``count`` is None (a closed loop has no end: give ``count``)."""
+    if count is None and traffic["loop"] == "closed":
+        raise ValueError("a closed loop's requests have no end; "
+                         "give a count")
+    return list(itertools.islice(requests(traffic, seed, vocab, seconds),
+                                 count))

@@ -55,6 +55,7 @@ def test_sound_run_is_correct_and_the_control_is_not(tmp_path):
                       "setup_s"}
     assert all(v["value"] > 0 for v in m.values())
     assert out["device"]["platform"] == "cpu"
+    assert out["requests_sent"] >= out["attempted"]
     assert list(out)[-1] == "checks"
     assert not out["controls"]["fp8"]["correct"], out["controls"]
 

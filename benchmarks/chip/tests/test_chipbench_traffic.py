@@ -1,6 +1,8 @@
 """The traffic generator: deterministic per seed, the stated
-distributions and clipping, and the same work for every seed."""
+distributions and clipping, the same work for every seed, and closed
+loops that never run dry."""
 
+import hashlib
 import json
 import math
 import sys
@@ -36,9 +38,10 @@ def _mix(name):
 @pytest.mark.parametrize("name", ["longdoc", "chat"])
 def test_same_seed_same_requests_other_seed_same_sizes(name):
     mix = _mix(name)
-    a = traffic.generate(mix, SEED, 32000, 30)
-    b = traffic.generate(mix, SEED, 32000, 30)
-    c = traffic.generate(mix, SEED + 1, 32000, 30)
+    n = 128 if mix["loop"] == "closed" else None
+    a = traffic.generate(mix, SEED, 32000, 30, n)
+    b = traffic.generate(mix, SEED, 32000, 30, n)
+    c = traffic.generate(mix, SEED + 1, 32000, 30, n)
     assert a == b
     assert [r.prompt for r in a] != [r.prompt for r in c]
     # one schedule of sizes and gaps for every seed, in the same order
@@ -65,7 +68,7 @@ def test_lengths_follow_the_stated_distributions_and_clip():
     inner = p[(p > 32) & (p < 3584)]
     assert np.std(np.log(inner)) == pytest.approx(1.0, rel=0.15)
     assert (p + o).max() <= 4096
-    ld = traffic.generate(_mix("longdoc"), SEED, 32000, 30)
+    ld = traffic.generate(_mix("longdoc"), SEED, 32000, 30, count=128)
     lp = np.array([len(r.prompt) for r in ld])
     lo = _mix("longdoc")["output_tokens"]
     assert 2304 <= lp.min() and lp.max() <= 3584
@@ -73,6 +76,37 @@ def test_lengths_follow_the_stated_distributions_and_clip():
     assert max(len(r.prompt) + r.max_new_tokens for r in ld) \
         <= 3584 + lo["max"]
     assert all(r.at == 0 for r in ld)
+
+
+# sha256 of ``[[prompt, max_new_tokens], ...]`` (``json.dumps``) of
+# ``longdoc``'s 128 requests for SEED from the generator that held a
+# closed loop's pool fixed at 128 (``"requests": 128``)
+FIXED_POOL_128 = ("c54e91868cd2fe7d4490894609da6b28"
+                  "e4d18df47e58ed3c7fede241b5b62d94")
+
+
+def test_a_closed_loops_first_waves_are_the_old_fixed_pools():
+    """Waves are drawn one after another as the clients take them, so
+    a program that sends as many as the old fixed pool held sends the
+    same requests it sent from that pool."""
+    first = traffic.generate(_mix("longdoc"), SEED, 32000, 51, count=128)
+    got = json.dumps([[r.prompt, r.max_new_tokens] for r in first])
+    assert hashlib.sha256(got.encode()).hexdigest() == FIXED_POOL_128
+
+
+def test_a_closed_loop_never_runs_dry():
+    """However fast the program, its clients find a next request: the
+    stream has no end, every wave holds the same prompt and answer
+    lengths, and a closed loop's whole list cannot be asked for."""
+    mix = json.loads((HERE / "tests" / "data" / "tiny-longdoc.json")
+                     .read_text())
+    c = mix["clients"]
+    stream = traffic.requests(mix, SEED, 100, 51)
+    waves = [[next(stream) for _ in range(c)] for _ in range(400)]
+    for f in (lambda r: len(r.prompt), lambda r: r.max_new_tokens):
+        assert len({tuple(sorted(map(f, w))) for w in waves}) == 1
+    with pytest.raises(ValueError):
+        traffic.generate(mix, SEED, 100, 51)
 
 
 def test_open_loop_gaps_are_exponential_at_the_rate():
