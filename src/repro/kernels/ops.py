@@ -22,7 +22,7 @@ import numpy as np
 
 from . import ref
 from .decode_attention import (decode_attention_fwd, mixed_attention_fwd,
-                               paged_attention_fwd)
+                               paged_attention_fwd, query_block_size)
 from .flash_attention import flash_attention_fwd
 from .mamba import mamba_scan_fwd
 from .rwkv6 import rwkv6_scan_fwd
@@ -167,9 +167,18 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                     v_scale: Optional[jnp.ndarray] = None,
                     pages_per_tile: Optional[int] = None) -> jnp.ndarray:
     """q: (T, Hq, D) flat token batch vs the PHYSICAL page pool
-    (N, ps, Hkv, D); tables (S, P), seg_ids/positions (T,) int32 ride as
-    scalar-prefetch operands so the kernel's index maps resolve
-    slot -> page id before each body runs.  A quantized pool (int8 /
+    (N, ps, Hkv, D); tables (S, P), seg_ids/positions (T,) int32.
+
+    The kernel cuts each slot's tokens into query blocks of
+    :func:`query_block_size` tokens, one grid row each, and fetches a
+    page once per block.  ``Scheduler._pad`` lays each slot's tokens
+    out as ONE contiguous span of the flat batch (speculative drafts
+    included) and the padding after them, so a block is a run of
+    consecutive tokens and the kernel launches ``cdiv(len, BQ)``
+    blocks per span; any other layout is still attended correctly.
+    The table and each block's descriptors ride as scalar-prefetch
+    operands so the kernel's index maps resolve slot -> page id before
+    each body runs.  A quantized pool (int8 /
     fp8_e4m3 codes) passes (N, ps, Hkv) fp32 ``k_scale``/``v_scale``;
     dequantization happens inside the kernel.  ``pages_per_tile``
     (default: :func:`default_pages_per_tile`) packs several pages per

@@ -237,12 +237,7 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     s, p = tables.shape
     scale = scale if scale is not None else d ** -0.5
 
-    # auto: on TPU always the kernel.  Elsewhere (the CPU interpret
-    # path) only when head_dim is lane-aligned — for d % 128 != 0 the
-    # wrapper lane-pads (copies) the ENTIRE page pool per layer per
-    # step, costing more than the gather it saves
-    if backend == "pallas" or (backend == "auto" and (
-            d % 128 == 0 or jax.default_backend() == "tpu")):
+    if paged_uses_kernel(backend, d):
         from ..kernels import ops as kops
         return kops.paged_attention(q, k_pages, v_pages, tables,
                                     seg_ids, positions, scale=scale,
@@ -277,6 +272,16 @@ def _paged_attention_ref(q, k_pages, v_pages, gidx, seg_ids, positions,
     # exactly the pre-paged executor path
     return mixed_attention(q, k_cache, v_cache, seg_ids, positions,
                            scale=scale, window=window, backend=backend)
+
+
+def paged_uses_kernel(backend: str, head_dim: int) -> bool:
+    """Whether :func:`paged_attention` under ``backend`` runs the Pallas
+    kernel.  "auto" takes it on TPU always; elsewhere (the CPU
+    interpret path) only when head_dim is lane-aligned — for
+    d % 128 != 0 the wrapper lane-pads (copies) the ENTIRE page pool
+    per layer per step, costing more than the gather it saves."""
+    return backend == "pallas" or (backend == "auto" and (
+        head_dim % 128 == 0 or jax.default_backend() == "tpu"))
 
 
 def select_paged_backend(requested: str, *, sharded: bool) -> str:

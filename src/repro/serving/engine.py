@@ -402,8 +402,11 @@ class ServingEngine:
         max-length sequence: page bytes × ``max_pages_per_seq`` — the
         capacity-planning number that shows the quantization win),
         ``n_replicas``, ``table_upload_rows`` (host→device
-        block-table rows flushed by the delta mirror), and
-        ``table_full_rebuilds``."""
+        block-table rows flushed by the delta mirror),
+        ``table_full_rebuilds``; the paged kernel:
+        ``attn_query_blocks`` (query blocks of scheduled tokens it
+        launched, 0 on the reference path) and the derived
+        ``attn_tokens_per_block`` (scheduled tokens per block)."""
         m = dict(self.scheduler.metrics)
         m.update(self._counters)
         m["bucket_compiles"] = self.executor.compile_count
@@ -417,6 +420,10 @@ class ServingEngine:
         m["n_replicas"] = self.n_replicas
         m["table_upload_rows"] = self.kv.upload_rows_total
         m["table_full_rebuilds"] = self.kv.upload_full_rebuilds
+        blocks = self.executor.attn_query_blocks
+        m["attn_query_blocks"] = blocks
+        m["attn_tokens_per_block"] = (
+            self.executor.attn_query_tokens / blocks if blocks else 0.0)
         m["spec_acceptance_rate"] = (
             m["accepted_tokens"] / m["proposed_tokens"]
             if m["proposed_tokens"] else 0.0)

@@ -46,7 +46,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import layers as L
-from ..models.attention import paged_attention, select_paged_backend
+from ..kernels.ops import query_block_size
+from ..models.attention import (paged_attention, paged_uses_kernel,
+                                select_paged_backend)
 from ..models import lm as LM
 from . import quant, sampling
 from .kv_cache import PagedKVCache
@@ -109,6 +111,11 @@ class Executor:
         # single-device whole-pool construct (see select_paged_backend)
         self._attn_backend = select_paged_backend(
             cfg.attn_backend, sharded=(mesh is not None or n_replicas > 1))
+        # query blocks the paged kernel launched and the tokens they
+        # carried, summed over steps (both stay 0 on the ref path)
+        self._attn_kernel = paged_uses_kernel(self._attn_backend, cfg.hd)
+        self.attn_query_blocks = 0
+        self.attn_query_tokens = 0
         # KV pages keep THIS sharding across steps: constrained on the
         # step outputs so donation round-trips never reshard
         self._kv_sharding = kv_sharding
@@ -170,6 +177,14 @@ class Executor:
             if ks is not None:
                 kv.put_kv(ks, vs)
                 kv.put_scales(kss, vss)
+        if self._attn_kernel:
+            # the kernel cuts each span into blocks of bq query tokens
+            bq = query_block_size(self.cfg.n_heads // self.cfg.n_kv_heads,
+                                  plan.t_bucket)
+            self.attn_query_blocks += sum(
+                -(-(s.end - s.start + len(s.drafts)) // bq)
+                for s in plan.spans)
+            self.attn_query_tokens += plan.n_tokens
         if self.compile_count > n_compiled:
             self.compiled_buckets.append((plan.t_bucket, plan.p_bucket))
             if tr.enabled:

@@ -359,6 +359,144 @@ class TestPagedAttention:
         assert ops.default_pages_per_tile(64, 2) == 2
 
 
+def _block_layout(name, bq):
+    """(seg, pos) of a flat batch laid out as the scheduler lays it:
+    each slot's tokens one contiguous run, padding at the tail.  The
+    span lengths sit on either side of the query block size ``bq``."""
+    spans, pad = {
+        "bq-1": ([(0, 0, bq - 1), (1, 9, 1)], 0),
+        "bq": ([(0, 0, bq), (1, 9, 1)], 0),
+        "bq+1": ([(0, 0, bq + 1), (1, 9, 1)], 0),
+        # the benchmark cell's step, scaled down: decodes, then a long
+        # prefill chunk deep in its prompt
+        "chunk+decodes": ([(s, 20 + 5 * s, 1) for s in range(5)]
+                          + [(5, 60 - 2 * bq - 3, 2 * bq + 3)], 0),
+        "padding": ([(2, 3, bq + 2), (0, 30, 1)], 5),
+        "two-prefills": ([(3, 0, bq + 3), (1, 17, bq - 2), (0, 40, 1)],
+                         0),
+    }[name]
+    seg, pos = [], []
+    for slot, start, n in spans:
+        seg += [slot] * n
+        pos += list(range(start, start + n))
+    seg += [-1] * pad
+    pos += [0] * pad
+    return (jnp.asarray(seg, jnp.int32), jnp.asarray(pos, jnp.int32))
+
+
+BLOCK_LAYOUTS = ["bq-1", "bq", "bq+1", "chunk+decodes", "padding",
+                 "two-prefills"]
+
+
+class TestPagedQueryBlocks:
+    """The paged kernel's query blocks: spans of one sequence share a
+    block's page fetches; spans crossing block boundaries, padding and
+    several prefills in one step must all match the oracle."""
+
+    # 16 query heads on 2 KV heads: G = 8, so a block holds 16 tokens
+    N_PAGES, PS, HKV, D, HQ, S, P = 96, 4, 2, 32, 16, 6, 16
+
+    def _pool(self, key_i, hkv=HKV, hq=HQ, t=1):
+        kp = rand(key_i, (self.N_PAGES, self.PS, hkv, self.D))
+        vp = rand(key_i + 1, (self.N_PAGES, self.PS, hkv, self.D))
+        q = rand(key_i + 2, (t, hq, self.D))
+        tables = TestPagedAttention()._tables(self.S, self.P,
+                                            self.N_PAGES, key_i + 3)
+        return q, kp, vp, tables
+
+    @pytest.mark.parametrize("layout", BLOCK_LAYOUTS)
+    @pytest.mark.parametrize("kv_dtype", sorted(KV_TIERS))
+    def test_spans_across_block_boundaries(self, layout, kv_dtype):
+        bq = ops.query_block_size(self.HQ // self.HKV, 64)
+        seg, pos = _block_layout(layout, bq)
+        q, kp, vp, tables = self._pool(90, t=seg.shape[0])
+        TestPagedAttention()._check_tier(q, kp, vp, tables, seg, pos,
+                                       kv_dtype)
+
+    @pytest.mark.parametrize("layout", ["chunk+decodes", "two-prefills"])
+    def test_window_6_across_blocks(self, layout):
+        bq = ops.query_block_size(self.HQ // self.HKV, 64)
+        seg, pos = _block_layout(layout, bq)
+        q, kp, vp, tables = self._pool(95, t=seg.shape[0])
+        out = ops.paged_attention(q, kp, vp, tables, seg, pos, window=6)
+        exp = ref.paged_attention(q, kp, vp, tables, seg, pos, window=6)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                                   **PAGED_TOL_F32)
+
+    @pytest.mark.parametrize("window", [None, 6])
+    def test_mqa_group_of_8(self, window):
+        """gemma-2b's grouping: 8 query heads on ONE KV head."""
+        bq = ops.query_block_size(8, 64)
+        seg, pos = _block_layout("chunk+decodes", bq)
+        q, kp, vp, tables = self._pool(100, hkv=1, hq=8, t=seg.shape[0])
+        out = ops.paged_attention(q, kp, vp, tables, seg, pos,
+                                  window=window)
+        exp = ref.paged_attention(q, kp, vp, tables, seg, pos,
+                                  window=window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                                   **PAGED_TOL_F32)
+
+    def test_block_size_fills_the_mxu_rows(self):
+        assert ops.query_block_size(4, 128) == 32    # mistral: G = 4
+        assert ops.query_block_size(8, 128) == 16    # gemma-2b: G = 8
+        assert ops.query_block_size(4, 8) == 8       # capped at T
+        assert ops.query_block_size(256, 128) == 1
+
+    @pytest.mark.parametrize("contiguous", [True, False],
+                             ids=["scheduler-layout", "interleaved"])
+    @pytest.mark.parametrize("seed", range(3))
+    def test_block_descriptors_cover_every_token_once(self, seed,
+                                                      contiguous):
+        """Every token lands in exactly one block row, a block holds one
+        slot's tokens (consecutive ones, in the scheduler's layout), and
+        no more than NB blocks are used for any layout."""
+        from repro.kernels.decode_attention import query_blocks
+        rng = np.random.default_rng(seed)
+        n_slots, bq = 16, 8
+        for t in (8, 32, 128):
+            slots = rng.permutation(n_slots)[:rng.integers(1, n_slots)]
+            seg, pos = [], []
+            for sl in slots:
+                n = int(rng.integers(1, max(2, t // 3)))
+                if len(seg) + n > t:
+                    break
+                start = int(rng.integers(0, 500))
+                seg += [int(sl)] * n
+                pos += list(range(start, start + n))
+            n_live = len(seg)
+            seg += [-1] * (t - n_live)
+            pos += [0] * (t - n_live)
+            seg, pos = np.asarray(seg), np.asarray(pos)
+            if not contiguous:
+                perm = rng.permutation(t)
+                seg, pos = seg[perm], pos[perm]
+            b = min(bq, t)
+            nb = min(t, -(-t // b) + n_slots + 1)
+            rows, row_pos, slot, first, last, tok_row = map(
+                np.asarray, query_blocks(jnp.asarray(seg, jnp.int32),
+                                         jnp.asarray(pos, jnp.int32),
+                                         block_q=b, n_slots=n_slots,
+                                         n_blocks=nb))
+            used = rows[rows >= 0]
+            assert sorted(used.tolist()) == list(range(t))
+            assert (rows.reshape(-1)[tok_row] == np.arange(t)).all()
+            for i in range(nb):
+                r = rows[i][rows[i] >= 0]
+                if r.size == 0:
+                    assert last[i] == -1
+                    continue
+                assert len(set(seg[r].tolist())) == 1
+                if contiguous:
+                    assert (np.diff(r) == 1).all()
+                assert slot[i] == max(seg[r[0]], 0)
+                assert (first[i], last[i]) == (pos[r].min(), pos[r].max())
+                assert (row_pos[i][:r.size] == pos[r]).all()
+            # each slot's tokens (and the padding) cut into ceil(n / b)
+            expect = sum(-(-int((seg == v).sum()) // b)
+                         for v in np.unique(seg))
+            assert (rows[:, 0] >= 0).sum() == expect <= nb
+
+
 class TestRWKV6:
     @pytest.mark.parametrize("shape", [(2, 3, 128, 64), (1, 2, 96, 32),
                                        (1, 1, 64, 128)])

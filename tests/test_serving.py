@@ -1584,3 +1584,71 @@ class TestShardedParity:
             print("KERNEL-REF-OK")
         """)
         assert "KERNEL-REF-OK" in out
+
+
+class TestAttnQueryBlocks:
+    """The paged kernel's query-block counter, and the plan layout the
+    kernel relies on: each slot's tokens one contiguous run of the flat
+    batch, padding at the tail."""
+
+    def _run(self, backend):
+        # 32 query heads on one KV head: a query block holds 4 tokens,
+        # so prefill chunks of 8 and speculative spans of 5 cross blocks
+        cfg = LMConfig(name="serve-tiny", n_layers=1, d_model=128,
+                       n_heads=32, n_kv_heads=1, d_ff=128, vocab_size=97,
+                       param_dtype=jnp.float32, remat="none",
+                       attn_backend=backend)
+        params = init_params(cfg, jax.random.key(0))
+        eng = ServingEngine(cfg, params, page_size=4, num_pages=64,
+                            max_batch=4, chunk_size=8, token_budget=16,
+                            spec_k=4)
+        plans = []
+        execute = eng.executor.execute
+
+        def recording(plan, kv):
+            plans.append(plan)
+            return execute(plan, kv)
+
+        eng.executor.execute = recording
+        prompts = [[(3 + 7 * i) % 97 for i in range(20)],
+                   [5, 6, 7, 5, 6, 7, 5, 6], [1, 2, 1, 2, 1]]
+        ids = [eng.submit(p, 12) for p in prompts]
+        eng.run()
+        return cfg, eng, plans, [eng.result(r).out_tokens for r in ids]
+
+    def test_counter_counts_blocks_of_each_span_and_ref_counts_none(self):
+        from repro.kernels.ops import query_block_size
+        cfg, eng, plans, outs = self._run("pallas")
+        g = cfg.n_heads // cfg.n_kv_heads
+        lens = [[s.end - s.start + len(s.drafts) for s in p.spans]
+                for p in plans]
+        bqs = [query_block_size(g, p.t_bucket) for p in plans]
+        blocks = sum(-(-n // bq) for bq, ls in zip(bqs, lens) for n in ls)
+        # chunked prefill spans and speculative spans both crossed a
+        # block boundary
+        assert any(not s.decode and n > bq
+                   for p, bq, ls in zip(plans, bqs, lens)
+                   for s, n in zip(p.spans, ls))
+        assert any(s.drafts and n > bq
+                   for p, bq, ls in zip(plans, bqs, lens)
+                   for s, n in zip(p.spans, ls))
+        m = eng.metrics
+        assert m["attn_query_blocks"] == blocks > 0
+        assert m["attn_tokens_per_block"] == pytest.approx(
+            sum(p.n_tokens for p in plans) / blocks)
+        _, ref_eng, _, ref_outs = self._run("ref")
+        assert ref_eng.metrics["attn_query_blocks"] == 0
+        assert ref_eng.metrics["attn_tokens_per_block"] == 0.0
+        assert outs == ref_outs
+
+    def test_each_slot_is_one_contiguous_run(self):
+        _, _, plans, _ = self._run("ref")
+        assert plans
+        for p in plans:
+            seg = np.asarray(p.seg_ids)
+            live = int((seg >= 0).sum())
+            assert (seg[:live] >= 0).all() and (seg[live:] < 0).all()
+            assert live == p.n_tokens
+            for slot in np.unique(seg[:live]):
+                at = np.flatnonzero(seg == slot)
+                assert (np.diff(at) == 1).all()
